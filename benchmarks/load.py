@@ -205,6 +205,8 @@ def main(argv=None):
     ap.add_argument("--json", type=pathlib.Path, default=None,
                     help="merge rows into this JSON mapping")
     args = ap.parse_args(argv)
+    from repro.runtime import compile_cache
+    compile_cache.use_persistent_cache()
 
     res = asyncio.run(_run_load(args))
     report(args, res)

@@ -186,12 +186,11 @@ def sharded_rows():
         return
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
     from repro.data.pipeline import SyntheticASR
     from repro.launch.serve import asr_demo_engine, serve_mesh
     from repro.parallel import sharding as shlib
 
-    mesh = jax.make_mesh((2,), ("model",))
+    mesh = shlib.make_mesh((2,), ("model",))
     params = tds.init_tds(jax.random.PRNGKey(0), TDS_CONFIG)
     fc = FEATURE_CONFIG
     nfr = 8
@@ -203,9 +202,9 @@ def sharded_rows():
         feats = features.mfcc(x, fc, use_pallas=True, hot=True)[:, :nfr]
         return tds.forward_batched(p, TDS_CONFIG, feats, ss, axis="model")
 
-    step = jax.jit(compat.shard_map(body, mesh=mesh,
-                                    in_specs=(pspecs, P(), P()),
-                                    out_specs=(P(), P()), check_vma=False))
+    step = jax.jit(jax.shard_map(body, mesh=mesh,
+                                 in_specs=(pspecs, P(), P()),
+                                 out_specs=(P(), P()), check_vma=False))
     R = np.random.RandomState(0)
     ss = tds.init_batched_stream_state(TDS_CONFIG, 4)
     x = jnp.asarray(R.randn(4, need).astype(np.float32))
@@ -244,12 +243,11 @@ def sharded_2d_rows():
         return
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro import compat
     from repro.data.pipeline import SyntheticASR
     from repro.launch.serve import asr_demo_engine, serve_mesh
     from repro.parallel import sharding as shlib
 
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = shlib.make_mesh((2, 2), ("data", "model"))
     params = tds.init_tds(jax.random.PRNGKey(0), TDS_CONFIG)
     fc = FEATURE_CONFIG
     nfr = 8
@@ -263,7 +261,7 @@ def sharded_2d_rows():
 
     ss = tds.init_batched_stream_state(TDS_CONFIG, 4)
     sspecs = shlib.asr_state_specs(ss, mesh)
-    step = jax.jit(compat.shard_map(
+    step = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(pspecs, sspecs, P("data", None)),
         out_specs=(P("data", None, None), sspecs), check_vma=False))
     R = np.random.RandomState(0)
@@ -440,6 +438,8 @@ def main(argv=None) -> None:
                     help="also write the name -> us_per_call mapping as "
                          "JSON (e.g. BENCH_decode.json at the repo root)")
     args = ap.parse_args(argv)
+    from repro.runtime import compile_cache
+    compile_cache.use_persistent_cache()
 
     wanted = [g.strip() for g in args.rows.split(",") if g.strip()]
     if "all" in wanted:

@@ -24,7 +24,7 @@ from repro.analysis.core import Context, Finding, ParsedModule
 _SHAPE_ATTRS = {"shape", "ndim", "dtype", "size", "aval", "sharding"}
 # module roots whose calls produce tracer values inside a jit trace
 _ARRAY_ROOTS = {"jnp", "jax", "lax"}
-_JIT_WRAPPERS = {"jit"}                 # jax.jit / compat aliases
+_JIT_WRAPPERS = {"jit"}                 # jax.jit
 _TRACE_CONSUMERS = {                    # callable-arg positions traced by jax
     "jit": (0,), "shard_map": (0,), "scan": (0,), "vmap": (0,),
     "pallas_call": (0,), "while_loop": (0, 1), "fori_loop": (2,),

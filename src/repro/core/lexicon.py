@@ -13,7 +13,7 @@ and `lm_advance`.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Dict, List, Sequence
 
 import jax
@@ -21,14 +21,18 @@ import jax.numpy as jnp
 import numpy as np
 
 
+# Lexicon and LM are pytrees so that jitted programs take their tables
+# as arguments: closed over, device arrays become program constants,
+# which a TPU must first copy back to the host to embed.
+@jax.tree_util.register_dataclass
 @dataclass(frozen=True)
 class Lexicon:
     """Padded trie over acoustic tokens."""
     children: jax.Array      # (n_nodes, C) int32 child node id, -1 = pad
     child_token: jax.Array   # (n_nodes, C) int32 acoustic token on the edge
     word_id: jax.Array       # (n_nodes,) int32 word id if word-final else -1
-    n_nodes: int
-    max_children: int
+    n_nodes: int = field(metadata=dict(static=True))
+    max_children: int = field(metadata=dict(static=True))
 
     @property
     def root(self) -> int:
@@ -62,11 +66,12 @@ def build_lexicon(words: Dict[str, Sequence[int]], max_children: int) -> Lexicon
                    n, max_children)
 
 
+@jax.tree_util.register_dataclass
 @dataclass(frozen=True)
 class BigramLM:
     """log P(w | prev). State = prev word id; start state = n_words."""
     table: jax.Array         # (n_words + 1, n_words) f32 log-probs
-    n_words: int
+    n_words: int = field(metadata=dict(static=True))
 
     @property
     def start_state(self) -> int:

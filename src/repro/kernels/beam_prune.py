@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 MASK = -1e30
 
@@ -58,7 +57,8 @@ def beam_prune_pallas(scores, beam, *, bn=1024, interpret=False):
         out_shape=jax.ShapeDtypeStruct((Np,), jnp.float32),
         scratch_shapes=[pltpu.SMEM((1,), jnp.float32)],
         interpret=interpret,
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
+        name="beam_prune",
     )(scores)
     return out[:N]

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 MASK = -1e30
 
@@ -112,7 +111,8 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=None,
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_attention",
     )(qr, kr, vr)
     return out.reshape(B, H, Sq, D)

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 
 def _kernel(xq_ref, wq_ref, xs_ref, ws_ref, o_ref, acc_ref, *, nk):
@@ -35,7 +34,7 @@ def _kernel(xq_ref, wq_ref, xs_ref, ws_ref, o_ref, acc_ref, *, nk):
     @pl.when(ik == nk - 1)
     def _done():
         o_ref[...] = (acc_ref[...].astype(jnp.float32)
-                      * xs_ref[...][:, None] * ws_ref[...][None, :])
+                      * xs_ref[...] * ws_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
@@ -53,13 +52,16 @@ def int8_matmul_pallas(xq, wq, xs, ws, *, bm=128, bn=128, bk=128,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bm,), lambda i, j, k: (i,)),
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
+            # scales as 2-D (bm, 1) / (1, bn) tiles: a 1-D block of a
+            # longer vector gets an XLA layout Mosaic refuses
+            pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
+            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-    )(xq, wq, xs, ws)
+        name="int8_matmul",
+    )(xq, wq, xs.reshape(M, 1), ws.reshape(1, N))

@@ -59,5 +59,6 @@ def norm_pallas(x, scale, bias=None, *, kind="layernorm", eps=1e-5, bt=256,
         out_specs=pl.BlockSpec((bt, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Tp, D), x.dtype),
         interpret=interpret,
+        name=kind,
     )(*args)
     return out[:T]

@@ -41,5 +41,6 @@ def logmel_pallas(power, fb, dct, *, bt=128, interpret=False):
         out_specs=pl.BlockSpec((bt, C), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Tp, C), jnp.float32),
         interpret=interpret,
+        name="logmel",
     )(power, fb, dct)
     return out[:T]

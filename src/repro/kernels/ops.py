@@ -234,36 +234,11 @@ def tds_conv(x, w, b, *, stride=1, relu=False, res=None, policy=None,
 # ---------------------------------------------------------------------------
 @functools.partial(jax.jit, static_argnames=("k", "beam", "mode"))
 def _hypothesis_unit(hashes, pb, pnb, *, k, beam, mode):
-    B, N = hashes.shape
-    assert N >= k, (N, k)
+    assert hashes.shape[1] >= k, (hashes.shape, k)
     if mode == "ref":
         return _ref.hypothesis_unit(hashes, pb, pnb, k=k, beam=beam)
-    valid = jnp.logaddexp(pb, pnb) > _ref.NEG_INF / 2
-    key = jnp.where(valid, hashes.astype(jnp.uint32), _ref.HASH_SENTINEL)
-    pad = (-N) % 128                       # lane-align the row for Mosaic
-    if pad:
-        key = jnp.pad(key, ((0, 0), (0, pad)),
-                      constant_values=_ref.HASH_SENTINEL)
-        pb = jnp.pad(pb, ((0, 0), (0, pad)), constant_values=_ref.NEG_INF)
-        pnb = jnp.pad(pnb, ((0, 0), (0, pad)), constant_values=_ref.NEG_INF)
-    # the hardware sort unit's ordering half: ONE batched XLA argsort;
-    # dead candidates carry an out-of-range uint32 sentinel, so a live
-    # hash equal to 2**31 - 1 can never be mistaken for one (the lane
-    # padding is merge-neutral: pads sort into the sentinel tail and
-    # contribute exact-zero mass, pinned bitwise by the parity tests
-    # against the unpadded ref pipeline)
-    order = jnp.argsort(key, axis=-1, stable=True)
-    key_s = jnp.take_along_axis(key, order, axis=-1)
-    pb_s = jnp.take_along_axis(pb, order, axis=-1)
-    pnb_s = jnp.take_along_axis(pnb, order, axis=-1)
-    pos, opb, opnb, oval = _hu.hypothesis_unit_pallas(
-        key_s, pb_s, pnb_s, k=k, beam=beam, interpret=mode != "mosaic")
-    valid = oval.astype(bool)
-    # order[pos] is the sorted segment head = the selected hash's FIRST
-    # occurrence in the original row (stable sort), matching the
-    # sort-free ref path; pruned slots pin to 0 in both paths
-    idx = jnp.where(valid, jnp.take_along_axis(order, pos, axis=-1), 0)
-    return {"idx": idx, "pb": opb, "pnb": opnb, "valid": valid}
+    return _hu.hypothesis_unit_pallas(hashes, pb, pnb, k=k, beam=beam,
+                                      interpret=mode != "mosaic")
 
 
 def hypothesis_unit(hashes, pb, pnb, k, beam, policy=None):

@@ -120,11 +120,9 @@ KERNEL_REGISTRY = {
         "shape_guard": "checked",   # bm/bn/bk asserted or halved to fit
     },
     "hypothesis_unit": {
-        "ref": ["hypothesis_unit", "merge_select_sorted"],
+        "ref": "hypothesis_unit",
         "entry": "hypothesis_unit_pallas",
         "test": "tests/test_hypothesis_unit.py",
-        "shape_guard": "fallback: callers route through "
-                       "ops._hypothesis_unit, which pads candidate rows "
-                       "to a multiple of 128 before the pallas_call",
+        "shape_guard": "checked",   # asserts the padded (rows, 128) tiles
     },
 }

@@ -76,82 +76,23 @@ NEG_INF = -1e30                      # matches core/hypothesis.py
 HASH_SENTINEL = np.uint32(0xFFFFFFFF)    # > any 31-bit prefix hash
 
 
-def _seg_lse(v, ids, num_segments, *, indices_are_sorted=False):
+def _seg_lse(v, ids, num_segments):
     """Per-segment logsumexp of flat `v`, broadcast back per position:
     out[j] = logsumexp(v over j's whole segment).
 
     max + exp + segment-sum (scatter) instead of a log2(n)-pass
     logaddexp scan — the scan's transcendentals dominated the
-    decode-hot-path merge.  Both hypothesis-unit paths (sorted-row
-    kernel, sort-free ref) call this one helper, accumulating segment
-    terms in original index order, which is what keeps them
-    bit-identical.  An all-dead channel stays exactly NEG_INF (the
-    exp(0)=1 terms of -1e30 entries would drift it by +log(count) ulps
-    otherwise)."""
-    m = jax.ops.segment_max(v, ids, num_segments=num_segments,
-                            indices_are_sorted=indices_are_sorted)
+    decode-hot-path merge.  Segment terms accumulate in original index
+    order — the order in which the sorted-row kernel
+    (kernels/hypothesis_unit.py) folds each segment into its head,
+    which is what keeps the two bit-identical.  An all-dead channel
+    stays exactly NEG_INF (the exp(0)=1 terms of -1e30 entries would
+    drift it by +log(count) ulps otherwise)."""
+    m = jax.ops.segment_max(v, ids, num_segments=num_segments)
     s = jax.ops.segment_sum(jnp.exp(v - m[ids]), ids,
-                            num_segments=num_segments,
-                            indices_are_sorted=indices_are_sorted)
+                            num_segments=num_segments)
     out = (m + jnp.log(s))[ids]
     return jnp.where(out > NEG_INF / 2, out, NEG_INF)
-
-
-def merge_select_sorted(key_s, pb_s, pnb_s, *, k: int, beam: float,
-                        iterative_topk: bool = False):
-    """One hypothesis-unit row over a candidate set PRE-SORTED by key.
-
-    key_s: (N,) uint32 — prefix hash for valid candidates, HASH_SENTINEL
-    for dead ones (so dead candidates sort to the tail and can never
-    merge with a live hash, even a live hash equal to 2**31 - 1).
-    pb_s / pnb_s: (N,) f32 CTC channels in the same sorted order.
-
-    Returns (pos, pb, pnb, valid), each (k,): `pos` indexes the SORTED
-    row (the caller maps it back through its argsort permutation),
-    pb/pnb are the merged channels of the selected representative, and
-    `valid` (int32 0/1) applies the beam threshold.
-
-    The sorted-row half of the hypothesis unit: the Pallas kernel
-    (kernels/hypothesis_unit.py) calls this per grid step.  The pure-jnp
-    ref path (`hypothesis_unit` below) is sort-free but shares
-    `_seg_lse`, summing each segment's terms in the same (original
-    index) order, which is what keeps interpret-mode parity
-    bit-for-bit.  `iterative_topk` picks the Mosaic-friendly k-pass
-    argmax selection (the kernel path; no sort primitive on TPU) over
-    one `lax.top_k` — both have the same semantics exactly (descending,
-    ties to the lowest index; the score domain is bounded below by
-    NEG_INF, never -inf, and k <= N, so the argmax loop can never
-    re-pick an exhausted slot).
-    """
-    n = key_s.shape[0]
-    head = jnp.concatenate(
-        [jnp.ones((1,), bool), key_s[1:] != key_s[:-1]])     # segment starts
-    ids = jnp.cumsum(head) - 1                               # segment ids
-    live = key_s != HASH_SENTINEL
-
-    pb_m = _seg_lse(pb_s, ids, n, indices_are_sorted=True)
-    pnb_m = _seg_lse(pnb_s, ids, n, indices_are_sorted=True)
-
-    rep = head & live                       # one representative per live hash
-    tot = jnp.where(rep, jnp.logaddexp(pb_m, pnb_m), NEG_INF)
-    best = jnp.max(tot)
-
-    if iterative_topk:
-        def pick(i, carry):
-            t, pos = carry
-            j = jnp.argmax(t).astype(jnp.int32)   # ties -> lowest index
-            return t.at[j].set(-jnp.inf), pos.at[i].set(j)
-
-        _, pos = jax.lax.fori_loop(
-            0, k, pick, (tot, jnp.zeros((k,), jnp.int32)))
-        top = tot[pos]
-    else:
-        top, pos = jax.lax.top_k(tot, k)
-        pos = pos.astype(jnp.int32)
-    valid = (top > NEG_INF / 2) & (top >= best - beam)
-    pb = jnp.where(valid, pb_m[pos], NEG_INF)
-    pnb = jnp.where(valid, pnb_m[pos], NEG_INF)
-    return pos, pb, pnb, valid.astype(jnp.int32)
 
 
 def hypothesis_unit(hashes, pb, pnb, *, k: int, beam: float):

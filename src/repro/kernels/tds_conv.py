@@ -20,26 +20,27 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _kernel(x_ref, w_ref, b_ref, *rest, k, stride, bt, W, Cin, Cout, relu):
+def _kernel(x_ref, w_ref, b_ref, *rest, k, stride, bt, Cin, Cout, relu):
     # x_ref holds one slot's whole padded input (ASRPU keeps conv inputs
     # resident in shared memory between kernels; TDS inputs are small
     # enough that the VMEM analogue is exact).  Each grid step produces a
-    # bt-row tile of one slot.
+    # bt-row tile of one slot.  Tiles are (t, C, W): the 80 W columns
+    # sit on lanes and the channels on sublanes, so each tap is a
+    # t-batched (Cout, Cin) @ (Cin, W) matmul read straight from the ref
+    # along its untiled time axis, with no relayout in the kernel.
     res_ref, o_ref = (rest if len(rest) == 2 else (None, rest[0]))
-    i = pl.program_id(1)
-    x = x_ref[0]                         # (Tp, W*Cin)
-    w = w_ref[...]                       # (k, Cin, Cout)
-    start = i * bt * stride
-    acc = jnp.zeros((bt * W, Cout), jnp.float32)
+    start = pl.program_id(1) * (bt * stride)
+    acc = jnp.zeros((bt, Cout, x_ref.shape[-1]), jnp.float32)
     for j in range(k):
-        xj = jax.lax.dynamic_slice_in_dim(x, start + j, bt * stride, axis=0)
+        xj = x_ref[0, pl.ds(start + j, bt * stride)]   # (bt*stride, Cin, W)
         if stride > 1:
-            xj = xj.reshape(bt, stride, W * Cin)[:, 0]
-        xj = xj.reshape(bt * W, Cin)
-        acc += jax.lax.dot(xj.astype(jnp.float32),
-                           w[j].astype(jnp.float32))
-    acc = acc.reshape(bt, W, Cout) + b_ref[...][None, None, :]
-    acc = acc.reshape(bt, W * Cout)
+            xj = xj.reshape(bt, stride, Cin, xj.shape[-1])[:, 0]
+        wj = jnp.broadcast_to(w_ref[j].astype(jnp.float32)[None],
+                              (bt, Cout, Cin))
+        acc += jax.lax.dot_general(wj, xj.astype(jnp.float32),
+                                   (((2,), (1,)), ((0,), (0,))),
+                                   preferred_element_type=jnp.float32)
+    acc = acc + b_ref[...][None]
     if relu:
         acc = jnp.maximum(acc, 0.0)
     if res_ref is not None:
@@ -61,6 +62,10 @@ def tds_conv_pallas(x, w, b, res=None, *, stride=1, bt=32, relu=False,
     k - 1 in padded coords).  `bt` is halved until it divides the output
     length (same fallback as ops.int8_matmul's bm), so frame counts that
     are not a multiple of the tile still run.
+
+    The kernel sees (t, C, W) blocks: W (80) on lanes pads each tile by
+    128/80, where C (1-23 channels) on lanes would pad it 5.6-128x.  The
+    swaps of the last two axes around the call are XLA's.
     """
     squeeze = x.ndim == 3
     if squeeze:
@@ -74,25 +79,24 @@ def tds_conv_pallas(x, w, b, res=None, *, stride=1, bt=32, relu=False,
     bt = min(bt, t_out)
     while t_out % bt:
         bt //= 2
-    xf = x.reshape(B, Tp, W * Cin)
     in_specs = [
-        pl.BlockSpec((1, Tp, W * Cin), lambda s, i: (s, 0, 0)),
-        pl.BlockSpec((k, Cin, Cout), lambda s, i: (0, 0, 0)),
-        pl.BlockSpec((Cout,), lambda s, i: (0,)),
+        pl.BlockSpec((1, Tp, Cin, W), lambda s, i: (s, 0, 0, 0)),
+        pl.BlockSpec((k, Cout, Cin), lambda s, i: (0, 0, 0)),
+        pl.BlockSpec((Cout, 1), lambda s, i: (0, 0)),
     ]
-    args = [xf, w, b]
+    args = [x.swapaxes(2, 3), w.swapaxes(1, 2), b.reshape(Cout, 1)]
+    tile = pl.BlockSpec((1, bt, Cout, W), lambda s, i: (s, i, 0, 0))
     if res is not None:
-        in_specs.append(pl.BlockSpec((1, bt, W * Cout),
-                                     lambda s, i: (s, i, 0)))
-        args.append(res.reshape(B, t_out, W * Cout))
+        in_specs.append(tile)
+        args.append(res.swapaxes(2, 3))
     out = pl.pallas_call(
-        functools.partial(_kernel, k=k, stride=stride, bt=bt, W=W,
-                          Cin=Cin, Cout=Cout, relu=relu),
+        functools.partial(_kernel, k=k, stride=stride, bt=bt, Cin=Cin,
+                          Cout=Cout, relu=relu),
         grid=(B, t_out // bt),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bt, W * Cout), lambda s, i: (s, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, t_out, W * Cout), jnp.float32),
+        out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct((B, t_out, Cout, W), jnp.float32),
         interpret=interpret,
-    )(*args)
-    out = out.reshape(B, t_out, W, Cout)
+        name="tds_conv",
+    )(*args).swapaxes(2, 3)
     return out[0] if squeeze else out

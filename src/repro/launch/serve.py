@@ -49,6 +49,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.kernels.policy import KernelPolicy
 from repro.launch.steps import build_lm
+from repro.parallel.sharding import make_mesh
 from repro.serving import (AsrEngine, AsrProgram, EngineConfig, LmEngine,
                            LmProgram)
 
@@ -88,12 +89,12 @@ def serve_mesh(spec):
         if r < 1 or c < 1:
             raise SystemExit(f"--mesh {spec!r}: axes must be >= 1")
         _need(r * c, spec)
-        return jax.make_mesh((r, c), ("data", "model"))
+        return make_mesh((r, c), ("data", "model"))
     n_model = int(spec)
     if n_model <= 1:
         return None
     _need(n_model, n_model)
-    return jax.make_mesh((n_model,), ("model",))
+    return make_mesh((n_model,), ("model",))
 
 
 def serve_lm(args):
@@ -143,20 +144,70 @@ def asr_demo_system():
     return tds_cfg, words, lex, lm, params, DECODER_CONFIG
 
 
+PAPER_LEXICON_WORDS = 4000     # a few thousand words, as in the paper's task
+
+
+def paper_asr_system(seed: int = 0):
+    """The paper's system at its published widths: `TDS_CONFIG` (79
+    kernels, widths 1200/1520/1840, 9000 outputs) under
+    `DECODER_CONFIG` (beam 128, trie fanout 32), with a lexicon of
+    `PAPER_LEXICON_WORDS` words and a bigram LM.  Weights, lexicon and
+    LM are random, generated from `seed`; nothing is read from disk.
+    Returns the same tuple as `asr_demo_system`."""
+    from repro.configs.tds_asr import DECODER_CONFIG, TDS_CONFIG
+    from repro.core import lexicon as lx
+    from repro.models import tds
+
+    n_words = PAPER_LEXICON_WORDS
+    rng = np.random.default_rng(seed)
+    fanout, vocab = DECODER_CONFIG.max_children, TDS_CONFIG.vocab_size
+    # grow the trie word by word: at each node reuse a child or, while
+    # the node has room, branch on a fresh token — fanout stays <= C
+    kids = [{}]
+    is_word = [False]
+    words = {}
+    while len(words) < n_words:
+        node, toks = 0, []
+        for _ in range(int(rng.integers(2, 7))):
+            if kids[node] and (len(kids[node]) >= fanout
+                               or rng.random() < 0.7):
+                tok = list(kids[node])[int(rng.integers(len(kids[node])))]
+            else:
+                tok = int(rng.integers(1, vocab))
+                while tok in kids[node]:
+                    tok = int(rng.integers(1, vocab))
+                kids[node][tok] = len(kids)
+                kids.append({})
+                is_word.append(False)
+            node = kids[node][tok]
+            toks.append(tok)
+        if not is_word[node]:
+            is_word[node] = True
+            words[f"w{len(words)}"] = toks
+    lex = lx.build_lexicon(words, max_children=fanout)
+    zipf = 1.0 / np.arange(1, n_words + 1)
+    counts = rng.poisson(n_words * zipf / zipf.sum() * 8.0,
+                         size=(n_words + 1, n_words))
+    lm = lx.bigram_from_counts(counts)
+    params = tds.init_tds(jax.random.PRNGKey(seed), TDS_CONFIG)
+    return TDS_CONFIG, words, lex, lm, params, DECODER_CONFIG
+
+
 def asr_demo_engine(n_slots: int, kernels: KernelPolicy = None,
                     mesh=None, max_queue=None,
                     overlap_psum: bool = False,
                     session_deadline=None, worker_watchdog=None,
-                    faults=None) -> tuple:
-    """(engine, words): an AsrEngine over the demo system's program.
-    `mesh` (see `serve_mesh`) shards the TDS FC/head weights over its
-    'model' axis — and, with a 'data' axis, the slot pool — running the
-    fused step under shard_map; `overlap_psum` enables the
+                    faults=None, system=None) -> tuple:
+    """(engine, words): an AsrEngine over `system` — the tuple
+    `asr_demo_system` or `paper_asr_system` returns (default: the demo
+    system).  `mesh` (see `serve_mesh`) shards the TDS FC/head weights
+    over its 'model' axis — and, with a 'data' axis, the slot pool —
+    running the fused step under shard_map; `overlap_psum` enables the
     latency-hiding psum split on the sharded contractions; `max_queue`
     is the admission backpressure bound (`EngineConfig.max_queue`);
     `session_deadline`/`worker_watchdog`/`faults` are the
     fault-tolerance knobs (see README "Fault tolerance")."""
-    tds_cfg, words, lex, lm, params, dec_cfg = asr_demo_system()
+    tds_cfg, words, lex, lm, params, dec_cfg = system or asr_demo_system()
     program = AsrProgram(tds_cfg, lex, lm, dec_cfg=dec_cfg,
                         ).with_beam_width(25.0)
     engine = AsrEngine(EngineConfig(program, n_slots=n_slots,
@@ -361,6 +412,8 @@ def main(argv=None):
                     help="--serve: bound on the SIGTERM graceful drain "
                          "(seconds; in-flight sessions finishing)")
     args = ap.parse_args(argv)
+    from repro.runtime import compile_cache
+    compile_cache.use_persistent_cache()
     if args.serve:
         return serve_network(args)
     if args.mode == "lm":

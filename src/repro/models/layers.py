@@ -19,7 +19,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-from repro import compat
 
 MASK_VALUE = -1e30
 
@@ -340,7 +339,7 @@ def attention_decode_sharded(q, k_cache, v_cache, qpos, kpos, *,
         acc_g = jax.lax.psum(acc_loc * w[..., None], "model")
         return m_g, l_g, acc_g
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(b, None, None, None), P(b, "model", None, None),
                   P(b, "model", None, None), P(b), P("model")),

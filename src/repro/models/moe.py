@@ -18,7 +18,6 @@ import jax
 from jax import lax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import MoESpec
 from repro.models import layers
 
@@ -217,17 +216,17 @@ def apply_moe_ep(p: dict, x: jax.Array, spec: MoESpec, act: str, sharder):
     if shared is None:
         def body2(rw, wg, wu, wd, xl):
             return body(rw, wg, wu, wd, None, xl)
-        fn = compat.shard_map(body2, mesh=mesh,
-                           in_specs=in_specs[:4] + (in_specs[5],),
-                           out_specs=(P(b_axes if b_axes else None,
-                                        "model", None), P()),
-                           check_vma=False)
+        fn = jax.shard_map(body2, mesh=mesh,
+                        in_specs=in_specs[:4] + (in_specs[5],),
+                        out_specs=(P(b_axes if b_axes else None,
+                                     "model", None), P()),
+                        check_vma=False)
         y, aux = fn(p["router"]["w"], p["w_gate"], p["w_up"], p["w_down"], x)
     else:
-        fn = compat.shard_map(body, mesh=mesh, in_specs=in_specs,
-                           out_specs=(P(b_axes if b_axes else None,
-                                        "model", None), P()),
-                           check_vma=False)
+        fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                        out_specs=(P(b_axes if b_axes else None,
+                                     "model", None), P()),
+                        check_vma=False)
         y, aux = fn(p["router"]["w"], p["w_gate"], p["w_up"], p["w_down"],
                     shared, x)
     return y, aux

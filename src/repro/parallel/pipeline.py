@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from repro import compat
 
 
 def pipeline_apply(stage_fn: Callable, stage_params, x_micro, mesh: Mesh,
@@ -63,7 +62,7 @@ def pipeline_apply(stage_fn: Callable, stage_params, x_micro, mesh: Mesh,
         # gather last stage's outputs to all (replicated output contract)
         return lax.psum(jnp.where(sid == n_stages - 1, outs, 0.0), axis)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(P(axis), P()), out_specs=P(),
         check_vma=False)

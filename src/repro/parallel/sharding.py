@@ -16,7 +16,24 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+
+
+def auto_axes(mesh: Optional[Mesh]) -> Optional[Mesh]:
+    """`mesh` with every axis typed Auto.  `jax.make_mesh` types its axes
+    Explicit, and then every op outside `shard_map` on an array sharded
+    over such an axis must resolve its output sharding from types alone —
+    a slot-reset scatter or an embedding gather raises ShardingTypeError.
+    The programs here leave those to GSPMD, so meshes are Auto."""
+    if mesh is None or all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
+
+def make_mesh(shape, axis_names) -> Mesh:
+    """`jax.make_mesh` over the visible devices, axes typed Auto."""
+    return auto_axes(jax.make_mesh(tuple(shape), tuple(axis_names)))
 
 
 def batch_axes(mesh: Optional[Mesh]):

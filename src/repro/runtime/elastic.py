@@ -55,7 +55,7 @@ def plan_remesh(n_devices: int, *, model_parallel: int,
 
 
 def build_mesh(plan: RemeshPlan):
-    return jax.make_mesh(plan.shape(), plan.axis_names())
+    return shlib.make_mesh(plan.shape(), plan.axis_names())
 
 
 def mesh_invariant_rng() -> None:

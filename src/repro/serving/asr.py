@@ -79,6 +79,7 @@ from typing import List
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import decoder as dec
 from repro.core import features
@@ -129,8 +130,6 @@ class AsrEngine(Engine):
         # device and every dispatch would then reshard them through an
         # implicit transfer (caught by no_implicit_transfers(strict=True))
         if mesh is not None:
-            from jax.sharding import NamedSharding
-            from jax.sharding import PartitionSpec as P
             dspec = ((P("data", None, None), P("data"))
                      if self._data_axis else (P(), P()))
             self._input_shardings = tuple(
@@ -144,6 +143,12 @@ class AsrEngine(Engine):
         # — and, under a mesh, weights are PLACED as feature-axis shards
         self.params, self._prepared = self.program.prepare_params(
             params, config.mesh)
+        # the lexicon trie and LM enter the jitted programs as arguments
+        # (replicated under a mesh), never as closed-over constants
+        self._tables = (self.program.lex, self.program.lm)
+        if mesh is not None:
+            self._tables = jax.device_put(
+                self._tables, NamedSharding(mesh, P()))
         self._jit_step = self._build_step()
         self._jit_reset = jax.jit(self._reset_slot_fn())
         self._jit_best = jax.jit(self._slot_best_fn(final=False))
@@ -186,7 +191,7 @@ class AsrEngine(Engine):
         spshard = self._slots_per_shard
         overlap = self.config.overlap_psum
 
-        def step(params, prepared, stream_state, beam_state, samples,
+        def step(params, prepared, tables, stream_state, beam_state, samples,
                  slots):
             # samples: (b, w, samples_per_window) — w buffered 80 ms
             # windows for each of the b gathered slots, extracted window
@@ -222,10 +227,17 @@ class AsrEngine(Engine):
                 use_int8=prog.use_int8, kernels=kernels, prepared=prepared,
                 axis=axis, overlap=overlap)
 
+            lex, lm = tables
+
             def expand(bst, lp):           # lp: (b, V) — one frame, all slots
-                return dec.expand_step_batched(bst, lp, prog.lex, prog.lm,
+                return dec.expand_step_batched(bst, lp, lex, lm,
                                                prog.dec_cfg, kernels), None
             new_bs, _ = jax.lax.scan(expand, bs, jnp.swapaxes(logp, 0, 1))
+            # keep the expansion's gathers out of the scatter-back
+            # fusions: fused into them at 2- and 4-row sub-batches, the
+            # TPU compiler aborts (fusion_emitter: "Check failed:
+            # GetGatherType(gather) == GatherType::kSublaneGather")
+            new_ss, new_bs = jax.lax.optimization_barrier((new_ss, new_bs))
 
             if data_axis is not None:
                 # out-of-range rows (pad, or another shard's slot — the
@@ -256,9 +268,6 @@ class AsrEngine(Engine):
         mesh = self.config.mesh
         if mesh is None:
             return jax.jit(step)
-        from jax.sharding import PartitionSpec as P
-
-        from repro import compat
         from repro.parallel import sharding as shlib
         pspecs = shlib.tds_param_specs(self.program.tds_cfg, mesh)
         qspecs = (shlib.tds_prepared_specs(self.program.tds_cfg, mesh)
@@ -272,15 +281,15 @@ class AsrEngine(Engine):
                             self.program.lm)))
             sspecs = shlib.asr_state_specs(ss_t, mesh)
             bspecs = shlib.asr_state_specs(bs_t, mesh)
-            return jax.jit(compat.shard_map(
+            return jax.jit(jax.shard_map(
                 step, mesh=mesh,
-                in_specs=(pspecs, qspecs, sspecs, bspecs,
+                in_specs=(pspecs, qspecs, P(), sspecs, bspecs,
                           P("data", None, None), P("data")),
                 out_specs=(sspecs, bspecs), check_vma=False))
         rep = P()
-        return jax.jit(compat.shard_map(
+        return jax.jit(jax.shard_map(
             step, mesh=mesh,
-            in_specs=(pspecs, qspecs, rep, rep, rep, rep),
+            in_specs=(pspecs, qspecs, rep, rep, rep, rep, rep),
             out_specs=(rep, rep), check_vma=False))
 
     def _reset_slot_fn(self):
@@ -301,10 +310,10 @@ class AsrEngine(Engine):
         eager version paid one dispatch per BeamState leaf per poll."""
         prog = self.program
 
-        def f(beam, slot):
+        def f(tables, beam, slot):
             st = dec.slot_state(beam, slot)
             if final:
-                st = dec.finalize(st, prog.lex, prog.lm, prog.dec_cfg)
+                st = dec.finalize(st, *tables, prog.dec_cfg)
             return dec.best(st)
 
         return f
@@ -337,7 +346,8 @@ class AsrEngine(Engine):
             # place the pool slot-axis-sharded from the start so the
             # sharded step never reshards it (outputs keep the
             # sharding via out_specs; resets/readouts go through
-            # plain jit, which GSPMD handles on sharded inputs)
+            # plain jit, which GSPMD partitions: EngineConfig types
+            # the mesh's axes Auto)
             from repro.parallel import sharding as shlib
             mesh = self.config.mesh
             stream_state = shlib.place_tree(
@@ -510,8 +520,8 @@ class AsrEngine(Engine):
             else:
                 batch_d, idx_d = jnp.asarray(batch), jnp.asarray(idx)
             new_ss, new_beam = self._jit_step(
-                self.params, self._prepared, self._stream_state, self._beam,
-                batch_d, idx_d)
+                self.params, self._prepared, self._tables,
+                self._stream_state, self._beam, batch_d, idx_d)
         if not commit:
             return
         self._stream_state, self._beam = new_ss, new_beam
@@ -616,7 +626,7 @@ class AsrEngine(Engine):
         if self._beam is None:
             return empty_hypothesis()
         fn = self._jit_best_final if final else self._jit_best
-        return dec.materialize_best(fn(self._beam, slot))
+        return dec.materialize_best(fn(self._tables, self._beam, slot))
 
     # ---- session mechanics -------------------------------------------
     def _push(self, session: Session, chunk) -> None:

@@ -292,6 +292,9 @@ class EngineConfig:
                 f"worker_watchdog must be None or > 0, got "
                 f"{self.worker_watchdog}")
         if self.mesh is not None:
+            from repro.parallel.sharding import auto_axes
+            # the pool's resets and readouts run outside shard_map
+            object.__setattr__(self, "mesh", auto_axes(self.mesh))
             if "model" not in self.mesh.axis_names:
                 raise ValueError(
                     f"serving mesh needs a 'model' axis, got {self.mesh}")

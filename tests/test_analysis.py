@@ -386,14 +386,13 @@ def test_rpl005_fires_on_shard_map_module(tmp_path):
     # plus PRNGKey init — mesh-dependent RNG would fork per data shard
     findings, _ = lint_snippet(tmp_path, """
         import jax
-        from repro import compat
         from jax.sharding import PartitionSpec as P
 
         def build(mesh):
             key = jax.random.PRNGKey(0)
             w = jax.random.normal(key, (8, 8))
-            step = compat.shard_map(lambda x: x, mesh=mesh,
-                                    in_specs=(P(),), out_specs=P())
+            step = jax.shard_map(lambda x: x, mesh=mesh,
+                                 in_specs=(P(),), out_specs=P())
             return step(w)
     """)
     assert codes(findings) == ["RPL005"]
@@ -403,7 +402,6 @@ def test_rpl005_fires_on_shard_map_module(tmp_path):
 def test_rpl005_clean_shard_map_with_mesh_invariant_rng(tmp_path):
     findings, _ = lint_snippet(tmp_path, """
         import jax
-        from repro import compat
         from repro.runtime.elastic import mesh_invariant_rng
         from jax.sharding import PartitionSpec as P
 
@@ -411,8 +409,8 @@ def test_rpl005_clean_shard_map_with_mesh_invariant_rng(tmp_path):
             with mesh_invariant_rng():
                 key = jax.random.PRNGKey(0)
                 w = jax.random.normal(key, (8, 8))
-            step = compat.shard_map(lambda x: x, mesh=mesh,
-                                    in_specs=(P(),), out_specs=P())
+            step = jax.shard_map(lambda x: x, mesh=mesh,
+                                 in_specs=(P(),), out_specs=P())
             return step(w)
     """)
     assert findings == []

@@ -55,8 +55,7 @@ def test_cache_rules_divisible(arch):
     from repro.launch.steps import cache_specs
     from repro.parallel.sharding import cache_shardings
 
-    from repro.compat import abstract_mesh
-    FakeMesh = abstract_mesh((16, 16), ("data", "model"))
+    FakeMesh = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
 
     cfg = get_config(arch)
     lm = LM(cfg)

@@ -33,6 +33,10 @@ def _candidates(seed, b, n, dup_rate=0.5, dead_rate=0.2):
 @pytest.mark.parametrize("seed,b,n,k,beam", [
     (0, 1, 12, 4, 5.0), (1, 3, 64, 16, 10.0), (2, 4, 200, 16, 3.0),
     (3, 2, 130, 32, 1e9),          # crosses the 128-lane pad boundary
+    (4, 8, 256, 128, 25.0),        # one full 8-slot block, k = 128 lanes
+    (5, 9, 150, 16, 5.0),          # slot rows padded to two 8-row blocks
+    (6, 2, 128, 16, 5.0),          # rows exactly one 128-lane tile wide
+    (7, 9, 256, 128, 5.0),         # two 8-row blocks, k = 128 lanes
 ])
 def test_fused_kernel_matches_ref_bit_for_bit(seed, b, n, k, beam):
     hashes, pb, pnb = _candidates(seed, b, n)

@@ -27,8 +27,12 @@ def test_train_launcher_moe_tiny():
     assert np.isfinite(losses).all()
 
 
-def test_serve_asr_launcher(capsys):
+def test_serve_asr_launcher(capsys, monkeypatch):
     from repro.launch import serve
+    # main() turns on the persistent compile cache; keep this worker's
+    # later compiles out of the checkout
+    monkeypatch.setattr("repro.runtime.compile_cache.use_persistent_cache",
+                        lambda: "off")
     serve.main(["--mode", "asr", "--utterances", "1"])
     out = capsys.readouterr().out
     assert "RTF" in out and "best words" in out

@@ -159,7 +159,6 @@ def test_kernel_policy_modes_under_shard_map(mode):
     """Hot-path ops resolve and lower inside a shard_map body in every
     CPU mode — the sharded engine step wraps the whole kernel sequence
     in one per-device program."""
-    from repro import compat
     from repro.kernels import ops
 
     mesh = jax.make_mesh((1,), ("model",))
@@ -172,8 +171,8 @@ def test_kernel_policy_modes_under_shard_map(mode):
         return ops.layernorm(x, s, b, policy=policy, hot=True)
 
     from jax.sharding import PartitionSpec as P
-    f = jax.jit(compat.shard_map(body, mesh=mesh, in_specs=(P(),),
-                                 out_specs=P(), check_vma=False))
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(),),
+                              out_specs=P(), check_vma=False))
     np.testing.assert_allclose(np.asarray(f(x)),
                                np.asarray(ops.layernorm(x, s, b,
                                                         policy=policy)),
@@ -187,7 +186,6 @@ def test_kernel_policy_modes_under_shard_map(mode):
 def test_forward_batched_sharded_matches_unsharded_fp32():
     from repro.models import tds
     from repro.parallel import sharding as shlib
-    from repro import compat
     from jax.sharding import PartitionSpec as P
 
     tds_cfg, words, lex, lm, params, dec_cfg = asr_demo_system()
@@ -202,9 +200,9 @@ def test_forward_batched_sharded_matches_unsharded_fp32():
     def body(p, f, s):
         return tds.forward_batched(p, tds_cfg, f, s, axis="model")
 
-    f = jax.jit(compat.shard_map(body, mesh=mesh,
-                                 in_specs=(pspecs, P(), P()),
-                                 out_specs=(P(), P()), check_vma=False))
+    f = jax.jit(jax.shard_map(body, mesh=mesh,
+                              in_specs=(pspecs, P(), P()),
+                              out_specs=(P(), P()), check_vma=False))
     got, got_st = f(placed, feats, st)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
@@ -396,7 +394,6 @@ def test_overlap_psum_matches_sync_engine():
 
 @multi_device
 def test_psum_overlap_matmul_matches_sync():
-    from repro import compat
     from repro.kernels import ops
     from jax.sharding import PartitionSpec as P
 
@@ -411,9 +408,9 @@ def test_psum_overlap_matmul_matches_sync():
         ovl = ops.psum_overlap_matmul(xloc, wloc, "model")
         return sync, ovl
 
-    f = jax.jit(compat.shard_map(body, mesh=mesh,
-                                 in_specs=(P(), P("model", None)),
-                                 out_specs=(P(), P()), check_vma=False))
+    f = jax.jit(jax.shard_map(body, mesh=mesh,
+                              in_specs=(P(), P("model", None)),
+                              out_specs=(P(), P()), check_vma=False))
     sync, ovl = f(x, w)
     np.testing.assert_allclose(np.asarray(ovl), np.asarray(sync),
                                rtol=1e-6, atol=1e-6)
