@@ -79,6 +79,7 @@ from typing import List
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import decoder as dec
@@ -219,38 +220,48 @@ class AsrEngine(Engine):
                 gidx = slots
             ss = jax.tree.map(lambda a: a[gidx], stream_state)
             bs = jax.tree.map(lambda a: a[gidx], beam_state)
-            feats = features.mfcc(samples, prog.feat_cfg, use_pallas=True,
-                                  kernels=kernels, hot=True)[:, :, :nfr]
-            feats = feats.reshape(b, w * nfr, -1)
-            logp, new_ss = tds.forward_batched(
-                params, prog.tds_cfg, feats, ss,
-                use_int8=prog.use_int8, kernels=kernels, prepared=prepared,
-                axis=axis, overlap=overlap)
+            # the named scopes label the device trace's ops by stage
+            # (op metadata only: HLO instruction names do not change)
+            with jax.named_scope("mfcc"):
+                feats = features.mfcc(samples, prog.feat_cfg,
+                                      use_pallas=True, kernels=kernels,
+                                      hot=True)[:, :, :nfr]
+                feats = feats.reshape(b, w * nfr, -1)
+            with jax.named_scope("tds_forward"):
+                logp, new_ss = tds.forward_batched(
+                    params, prog.tds_cfg, feats, ss,
+                    use_int8=prog.use_int8, kernels=kernels,
+                    prepared=prepared, axis=axis, overlap=overlap)
 
             lex, lm = tables
 
             def expand(bst, lp):           # lp: (b, V) — one frame, all slots
                 return dec.expand_step_batched(bst, lp, lex, lm,
                                                prog.dec_cfg, kernels), None
-            new_bs, _ = jax.lax.scan(expand, bs, jnp.swapaxes(logp, 0, 1))
-            # keep the expansion's gathers out of the scatter-back
-            # fusions: fused into them at 2- and 4-row sub-batches, the
-            # TPU compiler aborts (fusion_emitter: "Check failed:
-            # GetGatherType(gather) == GatherType::kSublaneGather")
-            new_ss, new_bs = jax.lax.optimization_barrier((new_ss, new_bs))
+            with jax.named_scope("expand"):
+                new_bs, _ = jax.lax.scan(expand, bs,
+                                         jnp.swapaxes(logp, 0, 1))
+            with jax.named_scope("writeback"):
+                # keep the expansion's gathers out of the scatter-back
+                # fusions: fused into them at 2- and 4-row sub-batches,
+                # the TPU compiler aborts (fusion_emitter: "Check failed:
+                # GetGatherType(gather) == GatherType::kSublaneGather")
+                new_ss, new_bs = jax.lax.optimization_barrier(
+                    (new_ss, new_bs))
 
-            if data_axis is not None:
-                # out-of-range rows (pad, or another shard's slot — the
-                # scheduler never builds those) drop instead of writing
-                widx = jnp.where(valid, loc, spshard)
+                if data_axis is not None:
+                    # out-of-range rows (pad, or another shard's slot —
+                    # the scheduler never builds those) drop instead of
+                    # writing
+                    widx = jnp.where(valid, loc, spshard)
 
-                def put(full, new):
-                    return full.at[widx].set(new, mode="drop")
-            else:
-                def put(full, new):
-                    return full.at[slots].set(new)
-            return (jax.tree.map(put, stream_state, new_ss),
-                    jax.tree.map(put, beam_state, new_bs))
+                    def put(full, new):
+                        return full.at[widx].set(new, mode="drop")
+                else:
+                    def put(full, new):
+                        return full.at[slots].set(new)
+                return (jax.tree.map(put, stream_state, new_ss),
+                        jax.tree.map(put, beam_state, new_bs))
 
         return step
 
@@ -310,13 +321,13 @@ class AsrEngine(Engine):
         eager version paid one dispatch per BeamState leaf per poll."""
         prog = self.program
 
-        def f(tables, beam, slot):
+        def readout(tables, beam, slot):     # "jit_readout" in a trace
             st = dec.slot_state(beam, slot)
             if final:
                 st = dec.finalize(st, *tables, prog.dec_cfg)
             return dec.best(st)
 
-        return f
+        return readout
 
     # ---- slot-pool state ---------------------------------------------
     def _reset_pool(self) -> None:
@@ -326,9 +337,9 @@ class AsrEngine(Engine):
         self._stream_state = None
         self._beam = None
         # (n_active, slot bucket b, window bucket w) per fused step —
-        # scheduling introspection for tests and benchmarks; bounded so
-        # a long-lived streaming engine doesn't accumulate one tuple
-        # per 80 ms step forever
+        # scheduling introspection for tests; bounded so a long-lived
+        # streaming engine doesn't accumulate one tuple per 80 ms step
+        # forever
         self.step_shapes: deque = deque(maxlen=4096)
 
     def _ensure_state(self) -> None:
@@ -425,11 +436,15 @@ class AsrEngine(Engine):
         w = max((b for b in self._buckets if (avail >= b).any()),
                 key=lambda b: (b * int((avail >= b).sum()), b))
         slots = [s for s in range(self.n_slots) if avail[s] >= w]
+        # slots holding a window that this step leaves out
+        parked = int((avail >= 1).sum()) - len(slots)
         self._ensure_state()
-        self._step_isolated(slots, w)
+        with TraceAnnotation("engine.step", n=len(slots), w=w,
+                             parked=parked):
+            self._step_isolated(slots, w, parked)
         return True
 
-    def _step_isolated(self, slots, w) -> None:
+    def _step_isolated(self, slots, w, parked: int = 0) -> None:
         """Run one gathered step with poison-slot isolation.  On
         failure the step is REPLAYED on bisected halves in probe mode
         (`_step_slots(..., commit=False)`) until the failure pins to
@@ -449,7 +464,7 @@ class AsrEngine(Engine):
         session to attribute a pinned fault to, so the fault re-raises
         there."""
         try:
-            self._step_slots(slots, w)
+            self._step_slots(slots, w, parked=parked)
             return
         except Exception as exc:
             if len(slots) == 1:
@@ -467,7 +482,7 @@ class AsrEngine(Engine):
             # unreproducible under probes: transient — one committed
             # full-set retry, then give up to the pool quarantine
             try:
-                self._step_slots(slots, w)
+                self._step_slots(slots, w, parked=parked)
             except Exception:
                 raise root
             return
@@ -479,7 +494,7 @@ class AsrEngine(Engine):
                 sess.sid, f"decoding step failed: {exc}", cause=exc))
         survivors = [s for s in slots if s not in {b for b, _ in bad}]
         if survivors:
-            self._step_isolated(survivors, w)
+            self._step_isolated(survivors, w, parked)
 
     def _probe_step_faults(self, slots, w):
         """Bisection probe: non-committing `_step_slots` replays that
@@ -495,30 +510,36 @@ class AsrEngine(Engine):
             return (self._probe_step_faults(slots[:mid], w)
                     + self._probe_step_faults(slots[mid:], w))
 
-    def _step_slots(self, slots, w, commit: bool = True) -> None:
+    def _step_slots(self, slots, w, commit: bool = True,
+                    parked: int = 0) -> None:
         """One fused step over exactly `slots` at window count `w`,
         committed ONLY on success: the jitted step is functional (new
         state comes back as fresh arrays), so a raise before the final
         assignments leaves pool state, sample buffers, and metrics
         exactly as they were — the invariant `_step_isolated`'s
         bisection replay depends on.  `commit=False` runs the step and
-        discards the result (the isolation probe)."""
-        batch, idx = self._assemble_batch(slots, w)
-        b = idx.shape[0]
-        if self._faults is not None:
-            self._faults.check(
-                "asr_step", slots=tuple(slots),
-                sids=tuple(self._owner[s].sid for s in slots
-                           if self._owner[s] is not None))
-        # transfer-guarded: the batch/idx uploads are the ONLY intended
-        # host->device traffic per step; anything implicit (a stray
-        # numpy weight, a scalar readback inside dispatch) is a bug
-        with no_implicit_transfers():
-            if self._input_shardings is not None:
-                batch_d, idx_d = jax.device_put(
-                    (batch, idx), self._input_shardings)
-            else:
-                batch_d, idx_d = jnp.asarray(batch), jnp.asarray(idx)
+        discards the result (the isolation probe).  `parked` counts the
+        slots with a window that the step leaves out (metrics only)."""
+        with TraceAnnotation("asr.assemble", w=w) as span:
+            batch, idx = self._assemble_batch(slots, w)
+            b = idx.shape[0]
+            span.set_metadata(b=b)
+            if self._faults is not None:
+                self._faults.check(
+                    "asr_step", slots=tuple(slots),
+                    sids=tuple(self._owner[s].sid for s in slots
+                               if self._owner[s] is not None))
+            # transfer-guarded: the batch/idx uploads are the ONLY
+            # intended host->device traffic per step; anything implicit
+            # (a stray numpy weight, a scalar readback inside dispatch)
+            # is a bug
+            with no_implicit_transfers():
+                if self._input_shardings is not None:
+                    batch_d, idx_d = jax.device_put(
+                        (batch, idx), self._input_shardings)
+                else:
+                    batch_d, idx_d = jnp.asarray(batch), jnp.asarray(idx)
+        with TraceAnnotation("asr.dispatch"), no_implicit_transfers():
             new_ss, new_beam = self._jit_step(
                 self.params, self._prepared, self._tables,
                 self._stream_state, self._beam, batch_d, idx_d)
@@ -529,7 +550,7 @@ class AsrEngine(Engine):
         self._slot_steps[slots] += w
         self.n_steps += 1
         self.step_shapes.append((len(slots), b, w))
-        self.metrics.on_step(len(slots), b)
+        self.metrics.on_step(len(slots), b, parked)
         for s in slots:
             if self._owner[s] is not None:      # slot-level API has no owner
                 self.metrics.on_first_result(self._owner[s])
@@ -626,7 +647,8 @@ class AsrEngine(Engine):
         if self._beam is None:
             return empty_hypothesis()
         fn = self._jit_best_final if final else self._jit_best
-        return dec.materialize_best(fn(self._tables, self._beam, slot))
+        with TraceAnnotation("asr.readout"):
+            return dec.materialize_best(fn(self._tables, self._beam, slot))
 
     # ---- session mechanics -------------------------------------------
     def _push(self, session: Session, chunk) -> None:

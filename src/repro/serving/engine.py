@@ -35,6 +35,7 @@ import threading
 from typing import Iterator, List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serving.metrics import EngineMetrics
 
@@ -188,7 +189,8 @@ class Session:
         self._check_attached()
         if self.finished:
             raise RuntimeError(f"session {self.sid}: push after finish()")
-        self._engine._push(self, data)
+        with TraceAnnotation("engine.push", sid=self.sid):
+            self._engine._push(self, data)
         return self
 
     def poll(self) -> dict:
@@ -378,7 +380,8 @@ class Engine:
                 self._queue.remove(sess)
                 self._owner[slot] = sess
                 sess.slot = slot
-                self._admit_to_slot(sess, slot)
+                with TraceAnnotation("engine.admit", sid=sess.sid):
+                    self._admit_to_slot(sess, slot)
                 sess._pending = None
                 self.metrics.on_admit(sess)
                 did = True
@@ -391,7 +394,8 @@ class Engine:
         did = False
         for slot, sess in enumerate(self._owner):
             if sess is not None and self._ready_to_close(sess, slot):
-                sess.result = self._finalize_slot(slot)
+                with TraceAnnotation("engine.harvest", sid=sess.sid):
+                    sess.result = self._finalize_slot(slot)
                 sess.slot = None
                 self._owner[slot] = None
                 self.metrics.on_done(sess)

@@ -48,6 +48,7 @@ import warnings
 from typing import Callable, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serving.engine import (AdmissionRejected, Engine,
                                   SessionFaulted, copy_result)
@@ -290,19 +291,23 @@ class EngineWorker:
         try:
             busy = False
             while not self._stopping.is_set():
-                try:
-                    item = self._cmds.get(
-                        timeout=0.001 if busy else self._idle_wait)
-                except queue.Empty:
-                    item = None
+                with TraceAnnotation("worker.wait"):
+                    try:
+                        item = self._cmds.get(
+                            timeout=0.001 if busy else self._idle_wait)
+                    except queue.Empty:
+                        item = None
                 while item is not None:
-                    self._exec(*item)
+                    with TraceAnnotation("worker.exec"):
+                        self._exec(*item)
                     try:
                         item = self._cmds.get_nowait()
                     except queue.Empty:
                         item = None
-                busy = self._pump()
-                self._resolve_watchers()
+                with TraceAnnotation("worker.pump"):
+                    busy = self._pump()
+                with TraceAnnotation("worker.resolve"):
+                    self._resolve_watchers()
                 self.heartbeat = time.monotonic()
         except BaseException as exc:
             # the pump itself died (per-session faults are contained
