@@ -34,8 +34,11 @@ Each step runs on a GATHERED sub-batch, not the full masked pool: the
 scheduler picks the window count w maximizing retired windows
 (w x eligible slots, largest w on ties), gathers exactly the eligible
 slots into the smallest covering slot bucket (powers of two up to
-n_slots), and scatters their new state back.  Skipped slots are simply
-never written — per-slot trajectories are untouched (the acoustic
+n_slots), and scatters their new state back.  Slots holding fewer than
+w windows step in catch-up dispatches of the same pump round (the same
+rule over the slots not yet stepped), so every slot holding a window
+steps once per round.  Slots outside a dispatch are simply never
+written — per-slot trajectories are untouched (the acoustic
 forward and the expansion are row-independent in the slot axis, pinned
 bitwise by tests).  The old full-pool masked step paid B=n_slots
 compute however few slots were eligible, which made the ragged tail of
@@ -417,34 +420,43 @@ class AsrEngine(Engine):
 
     @worker_only
     def _step(self) -> bool:
-        """One fused decoding step over a gathered sub-batch.  The
-        scheduler picks the step bucket `w` retiring the most buffered
-        windows in one dispatch — w x (slots holding >= w windows),
-        largest w on ties (bulk decoding amortizes weight reads; live
-        streaming naturally runs w=1) — then gathers exactly the
-        eligible slots into the smallest covering slot bucket.  Slots
-        with fewer than w windows wait for a later, smaller-w pump
-        round and are NOT stepped (no masked full-pool compute: a
-        ragged tail of draining utterances steps at b=1/2, not
-        b=n_slots).  False (and nothing runs) when no slot can produce
-        output — all setup threads returned zero."""
+        """One pump round: every slot holding a whole window steps
+        exactly once, over at most one gathered dispatch per window
+        bucket.  The first dispatch takes the window bucket `w`
+        retiring the most buffered windows — w x (slots holding >= w
+        windows), largest w on ties (bulk decoding amortizes weight
+        reads) — and gathers exactly those slots into the smallest
+        covering slot bucket.  Catch-up dispatches then apply the same
+        rule to the slots not yet stepped this round (each holds fewer
+        than the previous dispatch's w, so every catch-up runs a
+        smaller w), until none holding a window is left: an utterance's
+        last windows step in the round they appear, so its slot is
+        harvested and refilled at once instead of waiting for the other
+        slots to reach their own tails.  Live streaming (one window per
+        slot) runs one w=1 dispatch.  False (and nothing runs) when no
+        slot can produce output — all setup threads returned zero."""
         self._flush_finished_tails()
         avail = np.array([self.slot_windows(s)
                           for s in range(self.n_slots)])
         if not (avail >= 1).any():
             return False
-        w = max((b for b in self._buckets if (avail >= b).any()),
-                key=lambda b: (b * int((avail >= b).sum()), b))
-        slots = [s for s in range(self.n_slots) if avail[s] >= w]
-        # slots holding a window that this step leaves out
-        parked = int((avail >= 1).sum()) - len(slots)
         self._ensure_state()
-        with TraceAnnotation("engine.step", n=len(slots), w=w,
-                             parked=parked):
-            self._step_isolated(slots, w, parked)
+        catchup = False
+        while (avail >= 1).any():
+            w = max((b for b in self._buckets if (avail >= b).any()),
+                    key=lambda b: (b * int((avail >= b).sum()), b))
+            slots = [s for s in range(self.n_slots) if avail[s] >= w]
+            avail[slots] = 0
+            # `parked`: slots holding a window that no dispatch of the
+            # round steps, 0 since the round runs until none is left;
+            # trace readers compute the parked share from it
+            with TraceAnnotation("engine.step", n=len(slots), w=w,
+                                 parked=0, catchup=int(catchup)):
+                self._step_isolated(slots, w, catchup)
+            catchup = True
         return True
 
-    def _step_isolated(self, slots, w, parked: int = 0) -> None:
+    def _step_isolated(self, slots, w, catchup: bool = False) -> None:
         """Run one gathered step with poison-slot isolation.  On
         failure the step is REPLAYED on bisected halves in probe mode
         (`_step_slots(..., commit=False)`) until the failure pins to
@@ -464,7 +476,7 @@ class AsrEngine(Engine):
         session to attribute a pinned fault to, so the fault re-raises
         there."""
         try:
-            self._step_slots(slots, w, parked=parked)
+            self._step_slots(slots, w, catchup=catchup)
             return
         except Exception as exc:
             if len(slots) == 1:
@@ -482,7 +494,7 @@ class AsrEngine(Engine):
             # unreproducible under probes: transient — one committed
             # full-set retry, then give up to the pool quarantine
             try:
-                self._step_slots(slots, w, parked=parked)
+                self._step_slots(slots, w, catchup=catchup)
             except Exception:
                 raise root
             return
@@ -494,7 +506,7 @@ class AsrEngine(Engine):
                 sess.sid, f"decoding step failed: {exc}", cause=exc))
         survivors = [s for s in slots if s not in {b for b, _ in bad}]
         if survivors:
-            self._step_isolated(survivors, w, parked)
+            self._step_isolated(survivors, w, catchup)
 
     def _probe_step_faults(self, slots, w):
         """Bisection probe: non-committing `_step_slots` replays that
@@ -511,15 +523,15 @@ class AsrEngine(Engine):
                     + self._probe_step_faults(slots[mid:], w))
 
     def _step_slots(self, slots, w, commit: bool = True,
-                    parked: int = 0) -> None:
+                    catchup: bool = False) -> None:
         """One fused step over exactly `slots` at window count `w`,
         committed ONLY on success: the jitted step is functional (new
         state comes back as fresh arrays), so a raise before the final
         assignments leaves pool state, sample buffers, and metrics
         exactly as they were — the invariant `_step_isolated`'s
         bisection replay depends on.  `commit=False` runs the step and
-        discards the result (the isolation probe).  `parked` counts the
-        slots with a window that the step leaves out (metrics only)."""
+        discards the result (the isolation probe).  `catchup` marks a
+        dispatch after the first of its pump round (metrics only)."""
         with TraceAnnotation("asr.assemble", w=w) as span:
             batch, idx = self._assemble_batch(slots, w)
             b = idx.shape[0]
@@ -550,7 +562,7 @@ class AsrEngine(Engine):
         self._slot_steps[slots] += w
         self.n_steps += 1
         self.step_shapes.append((len(slots), b, w))
-        self.metrics.on_step(len(slots), b, parked)
+        self.metrics.on_step(len(slots), b, catchup)
         for s in slots:
             if self._owner[s] is not None:      # slot-level API has no owner
                 self.metrics.on_first_result(self._owner[s])

@@ -17,10 +17,12 @@ records events at the points the SLO story cares about:
   * steps       — fused-step count and step-shape occupancy: the
                   fraction of dispatched sub-batch rows that carried a
                   real active slot (bucket padding and idle LM slots
-                  burn compute without retiring work); `parked_slots`
-                  sums, over the steps, the slots that held input to
-                  decode but were left out of the step (ASR: fewer
-                  buffered windows than the step's window count)
+                  burn compute without retiring work).  ASR steps in
+                  pump rounds: `catchup_steps` counts the dispatches
+                  after a round's first, which step the slots holding
+                  fewer windows than its window count; `parked_slots`
+                  counts slots that held input no dispatch of their
+                  round stepped (0: every round steps them all)
 
 Latencies are held in bounded reservoirs (`LatencyStat`) so a long-lived
 streaming engine does not grow without bound; percentiles are computed
@@ -85,7 +87,8 @@ class EngineMetrics:
         self.steps = 0
         self.stepped_slots = 0        # real active slots across all steps
         self.dispatched_rows = 0      # sub-batch rows incl. bucket padding
-        self.parked_slots = 0         # slots with input a step left out
+        self.catchup_steps = 0        # ASR: dispatches after a round's first
+        self.parked_slots = 0         # slots with input a round left out
         self.queue_wait = LatencyStat()
         self.first_result = LatencyStat()
         self.finalize = LatencyStat()
@@ -112,14 +115,15 @@ class EngineMetrics:
             self.max_queue_depth = depth
 
     # ---- progress ----------------------------------------------------
-    def on_step(self, n_active: int, n_rows: int, n_parked: int = 0) -> None:
+    def on_step(self, n_active: int, n_rows: int,
+                catchup: bool = False) -> None:
         """One fused step advanced `n_active` real slots through a
-        dispatch shaped for `n_rows` sub-batch rows, and left out
-        `n_parked` slots that held input."""
+        dispatch shaped for `n_rows` sub-batch rows; `catchup` marks a
+        dispatch after the first of its pump round."""
         self.steps += 1
         self.stepped_slots += n_active
         self.dispatched_rows += n_rows
-        self.parked_slots += n_parked
+        self.catchup_steps += catchup
 
     def on_first_result(self, session) -> None:
         if session._t_first is not None or session._t_open is None:
@@ -182,6 +186,7 @@ class EngineMetrics:
                 "count": self.steps,
                 "stepped_slots": self.stepped_slots,
                 "dispatched_rows": self.dispatched_rows,
+                "catchup_steps": self.catchup_steps,
                 "parked_slots": self.parked_slots,
                 "occupancy": None if occ is None else round(occ, 4),
             },
