@@ -278,7 +278,7 @@ def logp_diffs(engine, audio) -> tuple:
     from repro.models import tds
 
     prog = engine.program
-    cfg, fc = prog.tds_cfg, prog.feat_cfg
+    cfg, fc = prog.acoustic, prog.feat_cfg
     sig = jnp.asarray(audio)[None]
     state = tds.init_batched_stream_state(cfg, 1)
 

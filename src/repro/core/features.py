@@ -4,6 +4,7 @@ Pipeline: pre-emphasis -> 25ms/10ms framing -> Hamming window -> |FFT|^2
 -> mel filterbank (80 banks) -> log -> DCT-II -> 80-dim MFCC.
 The hot post-FFT stages (mel matmul + log + DCT matmul) have a fused
 Pallas kernel (kernels/logmel.py); this module is the reference/driver.
+`logmel` stops before the DCT: the Conformer's 80 log-mel inputs.
 
 Streaming: `frames_producible` is the setup-thread arithmetic (paper §3.2)
 — how many whole frames fit in the buffered signal; `extract_frames`
@@ -71,17 +72,11 @@ def consumed_samples(n_frames: int, cfg: FeatureConfig) -> int:
     return n_frames * cfg.frame_shift
 
 
-def mfcc(signal: jax.Array, cfg: FeatureConfig = DEFAULT_FEATURE_CONFIG,
-         use_pallas: bool = False, kernels=None,
-         hot: bool = False) -> jax.Array:
-    """signal: (..., n_samples) f32 -> (..., n_frames, n_mfcc) f32.
-
-    Leading axes are batch (the serving engine extracts every slot's
-    window in one call — B slots fold into the logmel matmul's row
-    dimension).  use_pallas routes the mel+log+DCT tail through the
-    fused logmel kernel, dispatched by the `kernels` KernelPolicy
-    (None = auto; `hot` marks the call as decode-hot-path so auto never
-    picks the interpreter)."""
+def power_spectrum(signal: jax.Array,
+                   cfg: FeatureConfig = DEFAULT_FEATURE_CONFIG) -> jax.Array:
+    """signal: (..., n_samples) f32 -> (..., n_frames, n_fft//2+1) power
+    spectra: pre-emphasis, 25 ms / 10 ms framing, Hamming window,
+    |rfft|^2."""
     n = frames_producible(signal.shape[-1], cfg)
     assert n > 0, "not enough samples for one frame"
     # pre-emphasis
@@ -94,7 +89,21 @@ def mfcc(signal: jax.Array, cfg: FeatureConfig = DEFAULT_FEATURE_CONFIG,
     win = jnp.asarray(np.hamming(cfg.frame_len).astype(np.float32))
     frames = frames * win
     spec = jnp.fft.rfft(frames, n=cfg.n_fft, axis=-1)
-    power = jnp.square(jnp.abs(spec)).astype(jnp.float32)    # (..., n, n_bins)
+    return jnp.square(jnp.abs(spec)).astype(jnp.float32)
+
+
+def mfcc(signal: jax.Array, cfg: FeatureConfig = DEFAULT_FEATURE_CONFIG,
+         use_pallas: bool = False, kernels=None,
+         hot: bool = False) -> jax.Array:
+    """signal: (..., n_samples) f32 -> (..., n_frames, n_mfcc) f32.
+
+    Leading axes are batch (the serving engine extracts every slot's
+    window in one call — B slots fold into the logmel matmul's row
+    dimension).  use_pallas routes the mel+log+DCT tail through the
+    fused logmel kernel, dispatched by the `kernels` KernelPolicy
+    (None = auto; `hot` marks the call as decode-hot-path so auto never
+    picks the interpreter)."""
+    power = power_spectrum(signal, cfg)              # (..., n, n_bins)
     fb = jnp.asarray(mel_filterbank(cfg))
     dct = jnp.asarray(dct_matrix(cfg.n_mels, cfg.n_mfcc))
     if use_pallas:
@@ -104,6 +113,20 @@ def mfcc(signal: jax.Array, cfg: FeatureConfig = DEFAULT_FEATURE_CONFIG,
         return out.reshape(power.shape[:-1] + (out.shape[-1],))
     logmel = jnp.log(jnp.maximum(power @ fb, 1e-10))
     return logmel @ dct
+
+
+def logmel(signal: jax.Array, cfg: FeatureConfig = DEFAULT_FEATURE_CONFIG,
+           kernels=None, hot: bool = False) -> jax.Array:
+    """signal: (..., n_samples) f32 -> (..., n_frames, n_mels) log-mel
+    features, through the fused logmel kernel without its DCT (the
+    Conformer's front end).  No per-utterance normalisation: a stream
+    never sees its whole utterance."""
+    from repro.kernels import ops
+    power = power_spectrum(signal, cfg)
+    rows = power.reshape(-1, power.shape[-1])
+    out = ops.logmel(rows, jnp.asarray(mel_filterbank(cfg)), policy=kernels,
+                     hot=hot)
+    return out.reshape(power.shape[:-1] + (out.shape[-1],))
 
 
 def deltas(feats: jax.Array, window: int = 2) -> jax.Array:

@@ -58,7 +58,6 @@ class ASRPU:
         self._lex: Optional[Lexicon] = None
         self._lm: Optional[BigramLM] = None
         self._use_int8 = False
-        self._step_ms = 80.0
         self.plan: Optional[StepPlan] = None
         self._engine: Optional[AsrEngine] = None
 
@@ -70,7 +69,8 @@ class ASRPU:
         self._tds_cfg, self._params = tds_cfg, params
         self._feat_cfg = feat_cfg
         self._use_int8 = use_int8
-        self._step_ms = step_ms
+        # the window is the model's own: a step_ms other than TDS's
+        # 80 ms window raises here
         self.plan = make_step_plan(tds_cfg, feat_cfg, step_ms,
                                    self._dec_cfg.beam_size)
         self._reconfigure()
@@ -106,7 +106,7 @@ class ASRPU:
         # the engine-level trailing-window flush must not fire here.
         return AsrProgram(self._tds_cfg, self._lex, self._lm,
                           self._feat_cfg, self._dec_cfg,
-                          use_int8=self._use_int8, step_ms=self._step_ms,
+                          use_int8=self._use_int8,
                           max_windows_per_step=1, flush_tail=False)
 
     def _require_engine(self) -> AsrEngine:

@@ -7,18 +7,19 @@ schedule; a step whose buffers cannot produce a single output returns
 early exactly like a setup thread returning zero.  The plan doubles as
 the driver for the paper's instruction-count performance model
 (benchmarks/asrpu_model.py) and as the `Program` schedule of the serving
-engine (repro.serving): one `StepPlan` per configured acoustic program.
+engine (repro.serving): one `StepPlan` per configured acoustic program,
+whose window and kernel list come from the acoustic model
+(models/acoustic.py).
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import List
+from typing import List, Optional
 
 import numpy as np
 
-from repro.configs.tds_asr import (FEATURE_CONFIG, TDS_CONFIG, FeatureConfig,
-                                   TDSConfig)
-from repro.models import tds
+from repro.configs.tds_asr import FEATURE_CONFIG, TDS_CONFIG, FeatureConfig
+from repro.models.acoustic import model_of
 
 
 @dataclass
@@ -45,37 +46,36 @@ class StepPlan:
         return sum(k.n_threads for k in self.kernels)
 
 
-def make_step_plan(tds_cfg: TDSConfig = TDS_CONFIG,
-                   feat_cfg: FeatureConfig = FEATURE_CONFIG,
-                   step_ms: float = 80.0, beam_k: int = 128) -> StepPlan:
-    """The setup-thread arithmetic for one steady-state decoding step."""
-    samples = int(feat_cfg.sample_rate * step_ms / 1000)
-    feat_frames = int(step_ms / feat_cfg.shift_ms)          # 8 @ 80ms
-    sub = tds_cfg.total_subsample
-    assert feat_frames % sub == 0, (feat_frames, sub)
-    out_frames = feat_frames // sub
-    kernels = [PlannedKernel(
-        "mfcc", "feature", n_threads=feat_frames, n_frames=feat_frames,
-        macs_per_thread=(feat_cfg.frame_len                  # window+preemph
+def front_end_kernel(name: str, feat_cfg: FeatureConfig, n_frames: int,
+                     n_dct: int = 0) -> PlannedKernel:
+    """The front end's entry: one thread per feature frame (window and
+    pre-emphasis, FFT, mel filterbank, and a DCT to `n_dct` outputs if
+    the model takes cepstra)."""
+    return PlannedKernel(
+        name, "feature", n_threads=n_frames, n_frames=n_frames,
+        macs_per_thread=(feat_cfg.frame_len
                          + feat_cfg.n_fft * int(np.log2(feat_cfg.n_fft))
                          + (feat_cfg.n_fft // 2 + 1) * feat_cfg.n_mels
-                         + feat_cfg.n_mels * feat_cfg.n_mfcc),
-        weight_bytes=0, n_subkernels=1)]
-    t = feat_frames
-    for spec in tds.build_kernel_specs(tds_cfg):
-        t_out = t // spec.stride
-        if spec.kind == "layernorm":
-            kernels.append(PlannedKernel(
-                spec.name, spec.kind, n_threads=t_out, n_frames=t_out,
-                macs_per_thread=2 * spec.n_out, weight_bytes=0,
-                n_subkernels=1))
-        else:
-            # one thread per output neuron per frame (paper §3.1)
-            kernels.append(PlannedKernel(
-                spec.name, spec.kind, n_threads=t_out * spec.n_out,
-                n_frames=t_out, macs_per_thread=spec.n_in,
-                weight_bytes=spec.weight_bytes,
-                n_subkernels=spec.n_subkernels))
-        t = t_out
-    assert t == out_frames, (t, out_frames)
-    return StepPlan(samples, feat_frames, out_frames, kernels)
+                         + feat_cfg.n_mels * n_dct),
+        weight_bytes=0, n_subkernels=1)
+
+
+def make_step_plan(acoustic=TDS_CONFIG,
+                   feat_cfg: FeatureConfig = FEATURE_CONFIG,
+                   step_ms: Optional[float] = None,
+                   beam_k: int = 128) -> StepPlan:
+    """The setup-thread arithmetic for one steady-state decoding step of
+    the acoustic model `acoustic` (a TDSConfig or ConformerConfig): its
+    window and its kernel list, front end first.  `step_ms`, if given,
+    must be the model's own window (80 ms for TDS, 1.12 s for the
+    streaming Conformer's 14-frame chunk)."""
+    model = model_of(acoustic)
+    feat_frames, out_frames = model.window_frames(acoustic)
+    if step_ms is not None and step_ms != feat_frames * feat_cfg.shift_ms:
+        raise ValueError(
+            f"step_ms={step_ms} is not the {type(acoustic).__name__} "
+            f"window of {feat_frames * feat_cfg.shift_ms} ms")
+    kernels = model.plan_kernels(acoustic, feat_cfg)
+    assert kernels[-1].n_frames == out_frames, (kernels[-1], out_frames)
+    return StepPlan(feat_frames * feat_cfg.frame_shift, feat_frames,
+                    out_frames, kernels)

@@ -28,10 +28,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import (beam_prune as _bp, flash_attention as _fa,
-                           hypothesis_unit as _hu, int8_matmul as _im,
-                           layernorm as _ln, logmel as _lm, ref as _ref,
-                           tds_conv as _tc)
+from repro.kernels import (beam_prune as _bp, chunk_attention as _ca,
+                           flash_attention as _fa, hypothesis_unit as _hu,
+                           int8_matmul as _im, layernorm as _ln,
+                           logmel as _lm, ref as _ref, tds_conv as _tc)
 from repro.kernels.policy import (DEFAULT_POLICY, KernelPolicy,  # noqa: F401
                                   resolve)
 
@@ -197,11 +197,25 @@ def rmsnorm(x, scale, *, eps=1e-6, policy=None, hot=False):
                            interpret=mode != "mosaic")
 
 
-def logmel(power, fb, dct, policy=None, *, hot=False):
+def logmel(power, fb, dct=None, policy=None, *, hot=False):
+    """Log-mel features (`dct` None) or the MFCC tail, from power
+    spectra (T, F)."""
     mode = resolve(policy, hot=hot)
     if mode == "ref":
         return _ref.logmel(power, fb, dct)
     return _lm.logmel_pallas(power, fb, dct, interpret=mode != "mosaic")
+
+
+def chunk_attention(q, k, v, pos, u, vb, bias, *, chunk, policy=None,
+                    hot=False):
+    """Chunked relative-position self-attention of a streaming Conformer
+    (see kernels/chunk_attention.py for the layouts)."""
+    mode = resolve(policy, hot=hot)
+    if mode == "ref":
+        return _ref.chunk_attention(q, k, v, pos, u, vb, bias, chunk=chunk)
+    return _ca.chunk_attention_pallas(q, k, v, pos, u, vb, bias,
+                                      chunk=chunk,
+                                      interpret=mode != "mosaic")
 
 
 def beam_prune(scores, beam, policy=None):

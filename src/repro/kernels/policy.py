@@ -125,4 +125,10 @@ KERNEL_REGISTRY = {
         "test": "tests/test_hypothesis_unit.py",
         "shape_guard": "checked",   # asserts the padded (rows, 128) tiles
     },
+    "chunk_attention": {
+        "ref": "chunk_attention",
+        "entry": "chunk_attention_pallas",
+        "test": "tests/test_kernels.py",
+        "shape_guard": "checked",   # asserts whole chunks, 128 pos rows
+    },
 }

@@ -58,9 +58,36 @@ def rmsnorm(x, scale, eps=1e-6):
     return (xf * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
 
 
-def logmel(power, fb, dct):
-    """power: (T,F) f32, fb: (F,M), dct: (M,C) -> (T,C) MFCC tail."""
-    return jnp.log(jnp.maximum(power @ fb, 1e-10)) @ dct
+def logmel(power, fb, dct=None):
+    """power: (T,F) f32, fb: (F,M), dct: (M,C) -> (T,C) MFCC tail; without
+    `dct`, the (T,M) log-mel features themselves."""
+    out = jnp.log(jnp.maximum(power @ fb, 1e-10))
+    return out if dct is None else out @ dct
+
+
+def chunk_attention(q, k, v, pos, u, vb, bias, *, chunk):
+    """Chunked relative-position attention (kernels/chunk_attention.py).
+
+    q: (B,H,T,d) with T a whole number of chunks; k, v: (B,H,left+T,d),
+    the cached frames first; pos: (H,P,d), row m the projected encoding
+    of distance left + chunk - 1 - m; u, vb: (H,1,d); bias: (B,1,left+T)
+    additive.  Query t of chunk c = t // chunk attends to keys
+    [c*chunk, c*chunk + left + chunk) of k: the left context, then its
+    own chunk."""
+    B, H, T, d = q.shape
+    n = k.shape[2] - T + chunk
+    t = jnp.arange(T)
+    r = t % chunk
+    keys = (t - r)[:, None] + jnp.arange(n)[None, :]           # (T, n)
+    kw, vw = k[:, :, keys], v[:, :, keys]                      # (B,H,T,n,d)
+    rel = (chunk - 1 - r)[:, None] + jnp.arange(n)[None, :]    # (T, n)
+    hi = jax.lax.Precision.HIGHEST
+    ac = jnp.einsum("bhtd,bhtnd->bhtn", q + u[None], kw, precision=hi)
+    bd = jnp.einsum("bhtd,htnd->bhtn", q + vb[None], pos[:, rel],
+                    precision=hi)
+    s = (ac + bd) / (d ** 0.5) + bias[:, :, keys]
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhtn,bhtnd->bhtd", p, vw, precision=hi)
 
 
 def beam_prune(scores, beam, mask_value=MASK):

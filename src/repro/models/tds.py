@@ -26,6 +26,11 @@ import numpy as np
 from repro.configs.tds_asr import TDSConfig
 from repro.core import treeutil
 
+# the acoustic-model contract (models/acoustic.py)
+CONFIG = TDSConfig
+FRONT_END = "mfcc"
+SCOPE = "tds_forward"
+
 
 @dataclass(frozen=True)
 class KernelSpec:
@@ -89,6 +94,51 @@ def build_kernel_specs(cfg: TDSConfig) -> List[KernelSpec]:
     specs.append(KernelSpec("head", "fc", n_in=width, n_out=cfg.vocab_size,
                             weight_bytes=width * cfg.vocab_size))
     return specs
+
+
+def validate(cfg: TDSConfig, feat_cfg, use_int8: bool = False,
+             mesh=None) -> None:
+    """TDS serves every program option: int8 weights, a 'model' mesh."""
+
+
+def front_end(samples: jax.Array, feat_cfg, kernels=None) -> jax.Array:
+    """MFCC of each window, the mel+log+DCT tail through the fused
+    logmel kernel."""
+    from repro.core import features
+
+    return features.mfcc(samples, feat_cfg, use_pallas=True,
+                         kernels=kernels, hot=True)
+
+
+def window_frames(cfg: TDSConfig) -> tuple:
+    """(feature frames, CTC frames) of one decoding window: the frames
+    of one output frame, 80 ms at the paper's 8x subsampling."""
+    return cfg.total_subsample, 1
+
+
+def plan_kernels(cfg: TDSConfig, feat_cfg) -> list:
+    """The step plan's setup-thread entries: the MFCC front end, then
+    the TDS kernel sequence: one thread per output neuron per frame
+    (paper §3.1), LayerNorms one per frame."""
+    from repro.core.stepplan import PlannedKernel, front_end_kernel
+
+    t = window_frames(cfg)[0]
+    out = [front_end_kernel("mfcc", feat_cfg, t, n_dct=feat_cfg.n_mfcc)]
+    for spec in build_kernel_specs(cfg):
+        t_out = t // spec.stride
+        if spec.kind == "layernorm":
+            out.append(PlannedKernel(
+                spec.name, spec.kind, n_threads=t_out, n_frames=t_out,
+                macs_per_thread=2 * spec.n_out, weight_bytes=0,
+                n_subkernels=1))
+        else:
+            out.append(PlannedKernel(
+                spec.name, spec.kind, n_threads=t_out * spec.n_out,
+                n_frames=t_out, macs_per_thread=spec.n_in,
+                weight_bytes=spec.weight_bytes,
+                n_subkernels=spec.n_subkernels))
+        t = t_out
+    return out
 
 
 def kernel_census(cfg: TDSConfig) -> dict:

@@ -1,34 +1,42 @@
 """Streaming ASR engine: B utterance slots, ONE slot-native decoding step.
 
-The fused decoding step (paper §3.1: acoustic scoring — MFCC + the TDS
-kernel sequence — then one hypothesis expansion per emitted acoustic
-frame) is pure in all carried state, and slot-native END TO END:
-acoustic scoring runs through `tds.forward_batched` (the slot axis
-folds into the row dimension of every FC/LayerNorm matmul and conv tap
-— no per-slot vmap), the MFCC tail is the fused logmel kernel, int8
-programs use weights pre-quantized ONCE at engine build
-(`AsrProgram.prepare_params`), and hypothesis expansion is natively
-slot-batched (`decoder.expand_step_batched`): the shared lexicon trie /
-bigram table are gathered once over the flattened slot index set and
-the fused Pallas hypothesis unit runs with a batch grid axis.  Every
-pytree leaf of the TDS left-context state and of the `BeamState`
-carries a leading slot axis, each slot keeps its own sample buffer, and
-one jitted step advances every slot that has a full window buffered.
-Slots without a window are masked out — their carried state passes
-through unchanged — so each slot's trajectory is exactly the
+The fused decoding step (paper §3.1: acoustic scoring — the front end
+and the acoustic model — then one hypothesis expansion per emitted
+acoustic frame) is pure in all carried state, and slot-native END TO
+END.  The acoustic model comes from the program's acoustic configuration
+through the contract of `models/acoustic.py` (`AsrProgram.model`): the
+paper's TDS (MFCC front end, one CTC frame per 80 ms window) or the
+cache-aware streaming Conformer (log-mel front end, a 14-frame chunk per
+1.12 s window).  The step calls the model's `forward_batched`, resets a
+slot with its `reset_stream_slot` and builds the pool with its
+`init_batched_stream_state`; the window's length is the model's own
+(`AsrProgram.step_ms`, `make_step_plan`).  The slot axis folds into the
+row dimension of every matmul (no per-slot vmap), the front end's tail
+is the fused logmel kernel, int8 TDS programs use weights pre-quantized
+ONCE at engine build (`AsrProgram.prepare_params`), and hypothesis
+expansion is natively slot-batched (`decoder.expand_step_batched`): the
+shared lexicon trie / bigram table are gathered once over the flattened
+slot index set and the fused Pallas hypothesis unit runs with a batch
+grid axis.  Every pytree leaf of the model's carried state and of the
+`BeamState` carries a leading slot axis, each slot keeps its own sample
+buffer, and one jitted step advances every slot that has a full window
+buffered.  Slots without a window are masked out — their carried state
+passes through unchanged — so each slot's trajectory is exactly the
 single-stream decoder's.
 
 Window bookkeeping is the setup-thread arithmetic from core/features:
 `frames_producible` decides whether a slot can step (enough buffered
 samples for plan.feat_frames_per_step whole frames) and
-`consumed_samples` decides how many samples a step retires (the MFCC
+`consumed_samples` decides how many samples a step retires (the
 framing overlap stays buffered).  When a slot has several whole windows
 buffered (bulk decoding — `serve(utterances)`), one fused step consumes
 up to `AsrProgram.max_windows_per_step` of them at once: each window's
-samples are extracted exactly as a w=1 step would see them, so the fold
-is bit-identical to stepping windows one at a time, but every TDS
-weight matrix is read once per multi-window step instead of once per
-80 ms window (the acoustic forward is weight-bandwidth-bound at B=1).
+samples are extracted exactly as a w=1 step would see them, and the
+model carries its state across the windows of one step as across
+steps, so the fold matches stepping windows one at a time (bit-for-bit
+for TDS), but every weight matrix is read once per multi-window step
+instead of once per window (the acoustic forward is
+weight-bandwidth-bound at B=1).
 
 Each step runs on a GATHERED sub-batch, not the full masked pool: the
 scheduler picks the window count w maximizing retired windows
@@ -46,9 +54,9 @@ a utterance batch SLOWER than sequential decoding (a one-eligible-slot
 w=4 step cost ~4x its B=1 equivalent; see BENCH_decode.json's
 serve_asr_batched_b4 history).
 
-With `EngineConfig.mesh` set (a Mesh with a 'model' axis), the fused
-step runs under `shard_map`: FC/head weights live as feature-axis
-shards (`AsrProgram.prepare_params` places them), each device contracts
+With `EngineConfig.mesh` set (a Mesh with a 'model' axis; TDS only),
+the fused step runs under `shard_map`: FC/head weights live as
+feature-axis shards (`AsrProgram.prepare_params` places them), each device contracts
 its shard and psums partial products (`tds.forward_batched(axis=)`),
 and everything else — convs, LayerNorms, MFCC, hypothesis expansion —
 stays replicated.  mesh=None is the exact single-device path.
@@ -87,7 +95,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import decoder as dec
 from repro.core import features
-from repro.models import tds
 from repro.analysis.guards import no_implicit_transfers
 from repro.serving.config import AsrProgram, EngineConfig
 from repro.serving.engine import (Engine, Session, SessionFaulted,
@@ -177,17 +184,25 @@ class AsrEngine(Engine):
         out.append(self._slots_per_shard)
         return tuple(sorted(set(out)))
 
+    def step_entries(self) -> list:
+        """(slot bucket, window bucket) of every jit entry the fused step
+        can run, largest first: a warm-up that steps b slots holding w
+        windows each, for each pair, compiles every entry once."""
+        return [(b, w) for w in self._buckets
+                for b in reversed(self._slot_buckets)]
+
     def _step_fn(self):
         """One slot-native decoding step over a GATHERED sub-batch:
-        acoustic scoring (the fused logmel MFCC tail + the TDS kernel
-        sequence) runs natively over the gathered slot axis — every
-        FC/head/LayerNorm sees one (b*T, w*c)-row matmul and every conv
-        tap one (b*T*w, c)-row matmul — then each emitted acoustic
-        frame runs ONE natively batched hypothesis expansion (shared
-        lexicon/LM gathers over the flattened slot index set + the
-        fused hypothesis unit).  Only the gathered slots are written
-        back; every other slot's carried state is untouched."""
+        acoustic scoring (the front end's fused logmel tail + the
+        acoustic model's `forward_batched`) runs natively over the
+        gathered slot axis — the slot axis folds into the rows of every
+        matmul — then each emitted acoustic frame runs ONE natively
+        batched hypothesis expansion (shared lexicon/LM gathers over the
+        flattened slot index set + the fused hypothesis unit).  Only
+        the gathered slots are written back; every other slot's carried
+        state is untouched."""
         prog = self.program
+        model = prog.model
         nfr = self.plan.feat_frames_per_step
         kernels = self.config.kernels
         axis = "model" if self.config.mesh is not None else None
@@ -197,7 +212,7 @@ class AsrEngine(Engine):
 
         def step(params, prepared, tables, stream_state, beam_state, samples,
                  slots):
-            # samples: (b, w, samples_per_window) — w buffered 80 ms
+            # samples: (b, w, samples_per_window) — w buffered
             # windows for each of the b gathered slots, extracted window
             # by window (each row is exactly the signal a w=1 step would
             # see, so fusing windows is bit-identical to stepping them
@@ -225,14 +240,13 @@ class AsrEngine(Engine):
             bs = jax.tree.map(lambda a: a[gidx], beam_state)
             # the named scopes label the device trace's ops by stage
             # (op metadata only: HLO instruction names do not change)
-            with jax.named_scope("mfcc"):
-                feats = features.mfcc(samples, prog.feat_cfg,
-                                      use_pallas=True, kernels=kernels,
-                                      hot=True)[:, :, :nfr]
+            with jax.named_scope(model.FRONT_END):
+                feats = model.front_end(samples, prog.feat_cfg,
+                                        kernels=kernels)[:, :, :nfr]
                 feats = feats.reshape(b, w * nfr, -1)
-            with jax.named_scope("tds_forward"):
-                logp, new_ss = tds.forward_batched(
-                    params, prog.tds_cfg, feats, ss,
+            with jax.named_scope(model.SCOPE):
+                logp, new_ss = model.forward_batched(
+                    params, prog.acoustic, feats, ss,
                     use_int8=prog.use_int8, kernels=kernels,
                     prepared=prepared, axis=axis, overlap=overlap)
 
@@ -269,8 +283,9 @@ class AsrEngine(Engine):
         return step
 
     def _build_step(self):
-        """jit the fused step; with a mesh, wrap it in `shard_map` so
-        each device reads only its FC/head weight shard (psum-reduced
+        """jit the fused step; with a mesh (TDS only: `EngineConfig`
+        refuses one for other models), wrap it in `shard_map` so each
+        device reads only its FC/head weight shard (psum-reduced
         contractions inside `tds.forward_batched`).  On a 1D ('model',)
         mesh, slot state, samples, and the expansion stay replicated
         (PR 5's layout, bitwise-preserved); on a 2D ('data','model')
@@ -283,13 +298,14 @@ class AsrEngine(Engine):
         if mesh is None:
             return jax.jit(step)
         from repro.parallel import sharding as shlib
-        pspecs = shlib.tds_param_specs(self.program.tds_cfg, mesh)
-        qspecs = (shlib.tds_prepared_specs(self.program.tds_cfg, mesh)
+        prog = self.program
+        pspecs = shlib.tds_param_specs(prog.acoustic, mesh)
+        qspecs = (shlib.tds_prepared_specs(prog.acoustic, mesh)
                   if self._prepared is not None else P())
         if self._data_axis is not None:
             ss_t, bs_t = jax.eval_shape(
-                lambda: (tds.init_batched_stream_state(
-                            self.program.tds_cfg, self.n_slots),
+                lambda: (prog.model.init_batched_stream_state(
+                            prog.acoustic, self.n_slots),
                          dec.init_batched_state(
                             self.n_slots, self.program.dec_cfg.beam_size,
                             self.program.lm)))
@@ -307,14 +323,16 @@ class AsrEngine(Engine):
             out_specs=(rep, rep), check_vma=False))
 
     def _reset_slot_fn(self):
-        """One fused slot reset (utterance boundary): writing the fresh
-        left-context + beam leaves slot-by-slot in eager mode costs an
-        un-jitted scatter per pytree leaf, which dominated sequential
-        serving; fusing them makes admission O(one dispatch)."""
+        """One fused slot reset (utterance boundary): writing the model's
+        fresh carried state + beam leaves slot-by-slot in eager mode
+        costs an un-jitted scatter per pytree leaf, which dominated
+        sequential serving; fusing them makes admission O(one
+        dispatch)."""
         prog = self.program
 
         def reset(stream_state, beam, slot):
-            return (tds.reset_stream_slot(stream_state, slot, prog.tds_cfg),
+            return (prog.model.reset_stream_slot(stream_state, slot,
+                                                 prog.acoustic),
                     dec.reset_slot(beam, slot, prog.lm))
 
         return reset
@@ -351,8 +369,8 @@ class AsrEngine(Engine):
         # build + place locally, commit both attrs only once everything
         # succeeded: a device_put failure must not leave the pool with a
         # stream state but no beam (commit discipline, RPL008's pattern)
-        stream_state = tds.init_batched_stream_state(
-            self.program.tds_cfg, self.n_slots)
+        stream_state = self.program.model.init_batched_stream_state(
+            self.program.acoustic, self.n_slots)
         beam = dec.init_batched_state(
             self.n_slots, self.program.dec_cfg.beam_size,
             self.program.lm)
@@ -385,8 +403,9 @@ class AsrEngine(Engine):
         self.n_steps = old.n_steps
 
     def reset_slot(self, slot: int) -> None:
-        """Utterance boundary in one slot: clear its buffer, left
-        context, and hypothesis memory; other slots are untouched.
+        """Utterance boundary in one slot: clear its buffer, the model's
+        carried state (left context, caches), and hypothesis memory;
+        other slots are untouched.
 
         The jitted reset dispatch runs FIRST: it can raise (OOM, a
         poisoned donated buffer), and committing the cleared host-side
@@ -409,7 +428,7 @@ class AsrEngine(Engine):
             [self._slot_bufs[slot], np.asarray(samples, np.float32)])
 
     def slot_windows(self, slot: int) -> int:
-        """Setup-thread check: whole step_ms windows buffered in a slot."""
+        """Setup-thread check: whole decoding windows buffered in a slot."""
         return features.frames_producible(
             self._slot_bufs[slot].shape[0],
             self.program.feat_cfg) // self.plan.feat_frames_per_step
@@ -634,9 +653,12 @@ class AsrEngine(Engine):
         exactly one full window leaves the pure overlap after that step,
         so a flush runs at most once per session and utterances ending
         on a window boundary are untouched (bit-identical to the
-        unflushed path)."""
+        unflushed path).  The window's CTC frames that start in the
+        padding are counted (`EngineMetrics.tail_pad_frames`): encoder
+        work on zeros, up to a window's worth less one frame."""
         if not self.program.flush_tail:
             return
+        per_frame = self._spp // self.plan.acoustic_frames_per_step
         for slot, sess in enumerate(self._owner):
             if sess is None or not sess.finished:
                 continue
@@ -645,6 +667,8 @@ class AsrEngine(Engine):
                 self._slot_bufs[slot] = np.concatenate(
                     [self._slot_bufs[slot],
                      np.zeros((self._need - n,), np.float32)])
+                self.metrics.on_tail_pad(max(
+                    0, self.plan.acoustic_frames_per_step - -(-n // per_frame)))
 
     def pump(self) -> int:
         """Run decoding steps until no slot has a full window left."""

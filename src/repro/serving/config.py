@@ -19,30 +19,36 @@ from jax.sharding import Mesh
 
 from repro.configs.base import ModelConfig
 from repro.configs.tds_asr import (DECODER_CONFIG, FEATURE_CONFIG,
-                                   DecoderConfig, FeatureConfig, TDSConfig)
+                                   DecoderConfig, FeatureConfig)
 from repro.core.lexicon import BigramLM, Lexicon
 from repro.core.stepplan import StepPlan, make_step_plan
 from repro.kernels.policy import KernelPolicy
+from repro.models.acoustic import AcousticConfig, model_of
 
 
 @dataclass(frozen=True)
 class AsrProgram:
     """The streaming ASR decoding program (paper §3: one small decoder
-    program per stage — acoustic scoring then hypothesis expansion)."""
-    tds_cfg: TDSConfig
+    program per stage — acoustic scoring then hypothesis expansion).
+
+    `acoustic` is the acoustic model's configuration; its type picks the
+    model (`models.acoustic.model_of`): the paper's TDS or the
+    cache-aware streaming Conformer.  The model fixes the decoding
+    window (`step_ms`: 80 ms for TDS, one 14-frame chunk, 1.12 s, for
+    the Conformer) and the front end (MFCC or log-mel)."""
+    acoustic: AcousticConfig
     lex: Lexicon
     lm: BigramLM
     feat_cfg: FeatureConfig = FEATURE_CONFIG
     dec_cfg: DecoderConfig = DECODER_CONFIG
     use_int8: bool = False
-    step_ms: float = 80.0
-    # Upper bound on how many buffered step_ms windows ONE fused decoding
-    # step may consume (powers of two below it are the step buckets, like
+    # Upper bound on how many buffered windows ONE fused decoding step
+    # may consume (powers of two below it are the step buckets, like
     # LmProgram.prefill_buckets).  Live streaming still steps window by
     # window; bulk decoding (whole utterances buffered) folds up to this
     # many windows into the acoustic forward's row dimension, reading
-    # each FC weight matrix once per multi-window step instead of once
-    # per 80 ms window.  1 disables fusion.
+    # each weight matrix once per multi-window step instead of once per
+    # window.  1 disables fusion.
     max_windows_per_step: int = 4
     # On finish(), a session whose buffer still holds samples no decoded
     # frame has covered (more than the frame_len - frame_shift overlap a
@@ -59,6 +65,25 @@ class AsrProgram:
     # capped chunks.
     max_push_samples: int = 960_000
 
+    def __post_init__(self):
+        # a config no model serves raises TypeError, an option the model
+        # lacks ValueError
+        self.model.validate(self.acoustic, self.feat_cfg,
+                            use_int8=self.use_int8)
+
+    @property
+    def model(self):
+        """The acoustic model's module (the contract of
+        models/acoustic.py)."""
+        return model_of(self.acoustic)
+
+    @property
+    def step_ms(self) -> float:
+        """Audio one decoding window advances by, fixed by the model:
+        its feature frames per window times the frame shift."""
+        return (self.model.window_frames(self.acoustic)[0]
+                * self.feat_cfg.shift_ms)
+
     def step_buckets(self) -> Tuple[int, ...]:
         """Descending window counts a fused step may take (one jit entry
         each, traced lazily on first use)."""
@@ -70,8 +95,8 @@ class AsrProgram:
 
     def step_plan(self) -> StepPlan:
         """The static setup-thread schedule for one decoding step."""
-        return make_step_plan(self.tds_cfg, self.feat_cfg, self.step_ms,
-                              self.dec_cfg.beam_size)
+        return make_step_plan(self.acoustic, self.feat_cfg,
+                              beam_k=self.dec_cfg.beam_size)
 
     def prepare_params(self, params, mesh=None):
         """Build-time weight preparation for the decoding step, returning
@@ -87,19 +112,21 @@ class AsrProgram:
             replicated — so each device of the sharded engine step holds
             only its weight shard (`parallel.sharding.tds_param_specs`).
 
-        The engine passes both results straight into
-        `tds.forward_batched`."""
+        The engine passes both results straight into the model's
+        `forward_batched`.  Both are TDS-only: another acoustic model's
+        `validate` refuses int8 (`__post_init__`) and a mesh
+        (`EngineConfig`), and it gets its params back as they are."""
         prepared = None
         if self.use_int8:
             from repro.models import tds
-            prepared = tds.quantize_params(params, self.tds_cfg)
+            prepared = tds.quantize_params(params, self.acoustic)
         if mesh is not None:
             from repro.parallel import sharding as shlib
             params = shlib.place_tree(
-                params, shlib.tds_param_specs(self.tds_cfg, mesh), mesh)
+                params, shlib.tds_param_specs(self.acoustic, mesh), mesh)
             if prepared is not None:
                 prepared = shlib.place_tree(
-                    prepared, shlib.tds_prepared_specs(self.tds_cfg, mesh),
+                    prepared, shlib.tds_prepared_specs(self.acoustic, mesh),
                     mesh)
         return params, prepared
 
@@ -291,6 +318,10 @@ class EngineConfig:
             raise ValueError(
                 f"worker_watchdog must be None or > 0, got "
                 f"{self.worker_watchdog}")
+        if self.mesh is not None and isinstance(self.program, AsrProgram):
+            prog = self.program
+            prog.model.validate(prog.acoustic, prog.feat_cfg,
+                                use_int8=prog.use_int8, mesh=self.mesh)
         if self.mesh is not None:
             from repro.parallel.sharding import auto_axes
             # the pool's resets and readouts run outside shard_map

@@ -22,7 +22,10 @@ records events at the points the SLO story cares about:
                   after a round's first, which step the slots holding
                   fewer windows than its window count; `parked_slots`
                   counts slots that held input no dispatch of their
-                  round stepped (0: every round steps them all)
+                  round stepped (0: every round steps them all);
+                  `tail_pad_frames` counts the CTC frames computed on
+                  the zero padding of finished sessions' flushed last
+                  windows (work on no audio)
 
 Latencies are held in bounded reservoirs (`LatencyStat`) so a long-lived
 streaming engine does not grow without bound; percentiles are computed
@@ -89,6 +92,8 @@ class EngineMetrics:
         self.dispatched_rows = 0      # sub-batch rows incl. bucket padding
         self.catchup_steps = 0        # ASR: dispatches after a round's first
         self.parked_slots = 0         # slots with input a round left out
+        self.tail_pad_frames = 0      # ASR: CTC frames computed on the
+                                      # zero padding of flushed tails
         self.queue_wait = LatencyStat()
         self.first_result = LatencyStat()
         self.finalize = LatencyStat()
@@ -124,6 +129,11 @@ class EngineMetrics:
         self.stepped_slots += n_active
         self.dispatched_rows += n_rows
         self.catchup_steps += catchup
+
+    def on_tail_pad(self, n_frames: int) -> None:
+        """A finished session's partial last window was zero-padded to a
+        whole one; `n_frames` of its CTC frames start in the padding."""
+        self.tail_pad_frames += n_frames
 
     def on_first_result(self, session) -> None:
         if session._t_first is not None or session._t_open is None:
@@ -188,6 +198,7 @@ class EngineMetrics:
                 "dispatched_rows": self.dispatched_rows,
                 "catchup_steps": self.catchup_steps,
                 "parked_slots": self.parked_slots,
+                "tail_pad_frames": self.tail_pad_frames,
                 "occupancy": None if occ is None else round(occ, 4),
             },
             "latency": {

@@ -128,6 +128,17 @@ def test_logmel(t):
                                rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("t", [8, 112, 300])
+def test_logmel_without_dct(t):
+    """The Conformer's front end: the kernel stops at the log."""
+    p = jnp.abs(_arr(t, 257)) + 1e-3
+    fb = jnp.abs(_arr(257, 80))
+    got = ops.logmel(p, fb)
+    assert got.shape == (t, 80)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref.logmel(p, fb)),
+                               rtol=1e-5, atol=1e-5)
+
+
 @pytest.mark.parametrize("n,beam", [(100, 1.0), (1000, 5.0), (8448, 25.0)])
 def test_beam_prune(n, beam):
     s = _arr(n, scale=10.0)
@@ -175,3 +186,49 @@ def test_tds_conv_batched_fused_epilogue(batch, relu, residual):
             want = want + res[i]
         np.testing.assert_allclose(np.asarray(got[i]), np.asarray(want),
                                    rtol=1e-4, atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# chunked relative-position attention (streaming Conformer)
+# ---------------------------------------------------------------------------
+def _chunk_attention_loops(q, k, v, pos, u, vb, bias, chunk):
+    """The kernel's definition written query by query in numpy."""
+    q, k, v, pos, u, vb, bias = (np.asarray(a, np.float64)
+                                 for a in (q, k, v, pos, u, vb, bias))
+    B, H, T, d = q.shape
+    left = k.shape[2] - T
+    out = np.zeros(q.shape)
+    for t in range(T):
+        c, r = divmod(t, chunk)
+        keys = c * chunk + np.arange(left + chunk)
+        dist = left + r - np.arange(left + chunk)        # query - key
+        rows = left + chunk - 1 - dist                   # pos row of dist
+        s = (np.einsum("bhd,bhnd->bhn", q[:, :, t] + u[None, :, 0],
+                       k[:, :, keys])
+             + np.einsum("bhd,hnd->bhn", q[:, :, t] + vb[None, :, 0],
+                         pos[:, rows])) / np.sqrt(d) + bias[:, :, keys]
+        p = np.exp(s - s.max(-1, keepdims=True))
+        p /= p.sum(-1, keepdims=True)
+        out[:, :, t] = np.einsum("bhn,bhnd->bhd", p, v[:, :, keys])
+    return out
+
+
+@pytest.mark.parametrize("b,h,w,d,chunk,left,seen", [
+    (2, 2, 2, 16, 4, 8, 8), (3, 4, 4, 16, 4, 8, 0), (2, 1, 3, 32, 5, 10, 3),
+    (1, 8, 1, 64, 14, 70, 70), (2, 8, 4, 64, 14, 70, 28)])
+def test_chunk_attention(b, h, w, d, chunk, left, seen):
+    """Kernel (interpret) and ref against the definition written out,
+    with cached keys from before an utterance's start masked when fewer
+    than `left` frames were seen."""
+    from repro.kernels.chunk_attention import POS_ROWS
+    t, s = w * chunk, left + w * chunk
+    q, k, v = _arr(b, h, t, d), _arr(b, h, s, d), _arr(b, h, s, d)
+    pos, u, vb = _arr(h, POS_ROWS, d), _arr(h, 1, d), _arr(h, 1, d)
+    bias = jnp.where(jnp.arange(s) >= left - seen, 0.0, ref.MASK)
+    bias = jnp.broadcast_to(bias, (b, 1, s))
+    want = _chunk_attention_loops(q, k, v, pos, u, vb, bias, chunk)
+    for mode in ("ref", "interpret"):
+        got = ops.chunk_attention(q, k, v, pos, u, vb, bias, chunk=chunk,
+                                  policy=ops.KernelPolicy(mode))
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5,
+                                   atol=1e-5)

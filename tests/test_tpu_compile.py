@@ -2,7 +2,9 @@
 
 The serving path's Pallas kernels and the fused decoding step are
 compiled by the TPU compiler at the paper's widths (TDS_CONFIG,
-DECODER_CONFIG) for slot buckets 1 and 8.  That finds what interpret
+DECODER_CONFIG) for slot buckets 1 and 8, and the streaming
+FastConformer's `chunk_attention` kernel and fused step at its published
+widths (FASTCONFORMER_L_STREAM).  That finds what interpret
 mode cannot — tiling and layout refusals, unsupported primitives in a
 kernel body, compiler aborts — without a chip.  Nothing runs, so these
 say nothing about results or times.
@@ -113,6 +115,21 @@ def test_layernorm_compiles(one_chip, b, d):
                     S((b, d)), S((d,)), S((d,))) == {"layernorm"}
 
 
+@pytest.mark.parametrize("b,w", [(1, 1), (8, 4)])
+def test_chunk_attention_compiles(one_chip, b, w):
+    """17 x 512 FastConformer shapes: 8 heads of 64, chunk 14, 70 cached
+    frames, w fused windows."""
+    from repro.kernels.chunk_attention import POS_ROWS
+    S = _spec(one_chip)
+    t, s = 14 * w, 70 + 14 * w
+    assert _kernels(
+        lambda q, k, v, p, u, vb, m: ops.chunk_attention(
+            q, k, v, p, u, vb, m, chunk=14, policy=MOSAIC),
+        S((b, 8, t, 64)), S((b, 8, s, 64)), S((b, 8, s, 64)),
+        S((8, POS_ROWS, 64)), S((8, 1, 64)), S((8, 1, 64)),
+        S((b, 1, s))) == {"chunk_attention"}
+
+
 @pytest.fixture(scope="module")
 def paper_engine():
     from repro.launch.serve import asr_demo_engine, paper_asr_system
@@ -131,7 +148,7 @@ def test_fused_step_compiles(one_chip, paper_engine, b, w):
     eng, S = paper_engine, _spec(one_chip)
     prog = eng.program
     pool = jax.eval_shape(lambda: (
-        tds.init_batched_stream_state(prog.tds_cfg, eng.n_slots),
+        tds.init_batched_stream_state(prog.acoustic, eng.n_slots),
         dec.init_batched_state(eng.n_slots, prog.dec_cfg.beam_size,
                                prog.lm)))
     params, tables, stream, beam = jax.tree.map(
@@ -139,3 +156,42 @@ def test_fused_step_compiles(one_chip, paper_engine, b, w):
     assert _kernels(eng._jit_step, params, None, tables, stream, beam,
                     S((b, w, eng._need)), S((b,), jnp.int32)) == {
         "logmel", "tds_conv", "layernorm", "hypothesis_unit"}
+
+
+def test_conformer_fused_step_compiles(one_chip, paper_engine):
+    """The streaming FastConformer's whole decoding step at its published
+    widths, slot bucket 8 and window bucket 4 (56 encoder frames a row),
+    holds every kernel it runs as Mosaic.  Weights are shapes only, of
+    the benchmark reference's weight tree at the benchmark
+    configuration's sizes, which are these widths."""
+    import json
+    import sys
+    from pathlib import Path
+
+    from repro.configs.fastconformer import FASTCONFORMER_L_STREAM as cfg
+    from repro.core import decoder as dec
+    from repro.models import conformer
+    from repro.serving import AsrEngine, AsrProgram, EngineConfig
+
+    chip = Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+    if str(chip / "models") not in sys.path:
+        sys.path.insert(0, str(chip / "models"))
+    import fastconformer_ctc as fc
+    import fastconformer_ctc_reference as ref
+
+    sizes = json.loads((chip / "configs" / "fastconformer_l_stream.json")
+                       .read_text())["model"]
+    assert fc.conformer_config(sizes) == cfg
+    S = _spec(one_chip)
+    prog = AsrProgram(cfg, paper_engine.program.lex, paper_engine.program.lm)
+    params = jax.eval_shape(lambda: ref.init_params(
+        jax.random.PRNGKey(0), sizes))
+    eng = AsrEngine(EngineConfig(prog, n_slots=8, kernels=MOSAIC), params)
+    pool = jax.eval_shape(lambda: (
+        conformer.init_batched_stream_state(cfg, 8),
+        dec.init_batched_state(8, prog.dec_cfg.beam_size, prog.lm)))
+    params, tables, stream, beam = jax.tree.map(
+        lambda a: S(a.shape, a.dtype), (params, eng._tables) + pool)
+    assert _kernels(eng._jit_step, params, None, tables, stream, beam,
+                    S((8, 4, eng._need)), S((8,), jnp.int32)) == {
+        "logmel", "chunk_attention", "layernorm", "hypothesis_unit"}
